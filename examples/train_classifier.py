@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.core.policy import SsPropPolicy, paper_default
 from repro.core.schedulers import average_rate, drop_rate_for_step
 from repro.data.pipeline import ImagePipeline, ImagePipelineConfig
+from repro.launch import steps
 from repro.models import resnet
 from repro.optim import adam
 
@@ -48,21 +49,10 @@ def main():
         opt = adam.init(params)
         jits = {}
 
-        def loss_fn(p, x, y, pol):
-            logits = resnet.forward(args.model, p, x, pol)
-            return -jax.nn.log_softmax(logits)[jnp.arange(x.shape[0]), y].mean()
-
         def get(rate):
             if rate not in jits:
                 pol = paper_default(rate) if rate > 0 else SsPropPolicy(0.0)
-
-                @jax.jit
-                def f(p, o, x, y):
-                    lv, g = jax.value_and_grad(loss_fn)(p, x, y, pol)
-                    p2, o2, _ = adam.apply_updates(ocfg, p, g, o)
-                    return p2, o2, lv
-
-                jits[rate] = f
+                jits[rate] = jax.jit(steps.make_classifier_step(args.model, pol, ocfg))
             return jits[rate]
 
         return params, opt, get

@@ -34,7 +34,7 @@ import dataclasses
 import math
 from typing import Any
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 #: primitives that move data or control to the host from inside a
 #: jitted program — forbidden in audited train/serve steps.

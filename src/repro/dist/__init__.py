@@ -85,4 +85,3 @@ fault-tolerance protocol used by the training driver:
     (no backoff, no budget slot); the attempt function reads the
     excluded-rank list on entry and reshards around the survivors.
 """
-from repro.dist import compat as _compat  # noqa: F401  (installs jax shims)

@@ -1,59 +1,28 @@
-"""jax version compatibility + multi-process init for the distributed layer.
+"""Multi-process init for the distributed layer.
 
-Two concerns live here:
-
-1. **jax shims** — the production code targets the modern jax mesh API
-   (``jax.set_mesh``, ``jax.sharding.AxisType``); CI pins an older jax
-   where a ``Mesh`` is itself the context manager and meshes have no
-   axis types. ``install()`` backfills the small API surface we rely on
-   so the same driver code runs on both. It is idempotent and never
-   overwrites a real jax symbol.
-
-2. **``jax.distributed``-style multi-process init** — ``initialize()``
-   is the coordinator entry point every rank calls before training,
-   mirroring ``jax.distributed.initialize(coordinator_address,
-   num_processes, process_id)`` but coordinated through a *shared
-   filesystem directory* instead of a gRPC service. That is the same
-   substrate the fault protocol already uses (heartbeat files on the
-   checkpoint filesystem), needs no ports, and lets the multi-process
-   test harness spawn N real ranks as plain subprocesses sharing a
-   tmpdir. The returned :class:`ProcessGroup` carries the collective
-   primitives the control plane needs (``barrier``, ``put``/``gather``,
-   ``broadcast``) — *control-plane only*: scalars and JSON metadata,
-   never tensors. Tensor resharding stays on the checkpoint layer
-   (per-host shards + partial-read restore, ``repro.checkpoint.ckpt``).
+``initialize()`` is the coordinator entry point every rank calls before
+training, mirroring ``jax.distributed.initialize(coordinator_address,
+num_processes, process_id)`` but coordinated through a *shared
+filesystem directory* instead of a gRPC service. That is the same
+substrate the fault protocol already uses (heartbeat files on the
+checkpoint filesystem), needs no ports, and lets the multi-process
+test harness spawn N real ranks as plain subprocesses sharing a
+tmpdir. The returned :class:`ProcessGroup` carries the collective
+primitives the control plane needs (``barrier``, ``put``/``gather``,
+``broadcast``) — *control-plane only*: scalars and JSON metadata,
+never tensors. Tensor resharding stays on the checkpoint layer
+(per-host shards + partial-read restore, ``repro.checkpoint.ckpt``).
 """
 from __future__ import annotations
 
 from collections.abc import Sequence
-import contextlib
 import json
 import os
+import socket
 import time
 from typing import Any
 
 import jax
-
-
-@contextlib.contextmanager
-def _set_mesh(mesh):
-    """Old-jax stand-in for ``jax.set_mesh``: enter the physical mesh.
-
-    ``with``-usage ONLY. Modern jax also allows the bare-call global
-    setter form ``jax.set_mesh(mesh)``; old jax has no global mesh to
-    set, so on the shim that form would be a silent no-op — always
-    write ``with jax.set_mesh(mesh):`` in this codebase.
-    """
-    with mesh:
-        yield mesh
-
-
-def install() -> None:
-    if not hasattr(jax, "set_mesh"):
-        jax.set_mesh = _set_mesh
-
-
-install()
 
 
 # ----------------------------------------------------------------------
@@ -216,6 +185,8 @@ def initialize(
     )
     reg = {
         "pid": os.getpid(),
+        "host": socket.gethostname(),
+        "platform": jax.default_backend(),
         "local_devices": jax.local_device_count(),
         "registered_at": time.time(),
     }
@@ -234,6 +205,18 @@ def initialize(
             )
         time.sleep(pg.poll_s)
     return pg
+
+
+def registrations(coord_dir: str) -> dict[int, dict]:
+    """Rank -> the record it wrote at :func:`initialize` (pid, host,
+    platform, local device count)."""
+    d = os.path.join(coord_dir, "ranks")
+    out = {}
+    for r in registered_ranks(coord_dir):
+        reg = _read_json(os.path.join(d, f"rank_{r:05d}.json"))
+        if reg is not None:
+            out[r] = reg
+    return out
 
 
 def registered_ranks(coord_dir: str) -> list[int]:

@@ -15,10 +15,11 @@ Kernels:
     materialize the ``[M, C_in*Kh*Kw]`` patch buffer. The dynamic
     spatial offset (``oh*sh + kh*dh``) lands on a leading block-size-1
     axis whose index map computes the row arithmetically from the grid
-    coordinates; ``kw``/stride are static strided slices of the loaded
-    VMEM row. Grouped convs ride the same kernels in block-diagonal
-    form: operands carry an explicit group axis and the kept output
-    block's group indexes it (``block_idx[j] // blocks_per_group``).
+    coordinates; ``kw``/stride are static slices of the loaded VMEM
+    row, pre-split into column phases so no slice is strided. Grouped
+    convs ride the same kernels in block-diagonal form: operands carry
+    an explicit group axis and the kept output block's group indexes it
+    (``block_idx[j] // blocks_per_group``).
   * ``importance``   : imp[N]       = mean_M |dY|
 
 Grid iteration on TPU is sequential over the last axis, so accumulation
@@ -43,6 +44,22 @@ import jax.numpy as jnp
 from repro.kernels import specs
 
 
+def mxu_dot(a: jax.Array, b: jax.Array, dims) -> jax.Array:
+    """In-kernel ``dot_general`` accumulating in fp32.
+
+    Mosaic accepts a contract precision only for two fp32 operands, so
+    any other pair contracts at the default precision whatever
+    ``jax_default_matmul_precision`` says (products of 16-bit operands
+    are exact in fp32 anyway); an fp32 pair follows that setting.
+    """
+    both_f32 = a.dtype == b.dtype == jnp.float32
+    return jax.lax.dot_general(
+        a, b, dims,
+        precision=None if both_f32 else jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32,
+    )
+
+
 # ----------------------------------------------------------------------
 # dX = dY[:, kept] @ W[:, kept]^T  — gather fused via scalar prefetch.
 # ----------------------------------------------------------------------
@@ -55,12 +72,7 @@ def _dx_kernel(idx_ref, dy_ref, w_ref, out_ref, *, nk: int):
 
     dy_blk = dy_ref[...]  # [bm, bk]   kept block of dY
     w_blk = w_ref[...]    # [bn, bk]   same kept block of W (D_in rows)
-    out_ref[...] += jax.lax.dot_general(
-        dy_blk,
-        w_blk,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    out_ref[...] += mxu_dot(dy_blk, w_blk, (((1,), (1,)), ((), ())))
 
 
 def dx_gathered(
@@ -108,12 +120,7 @@ def _dw_kernel(idx_ref, x_ref, dy_ref, out_ref, *, nsteps: int):
 
     x_blk = x_ref[...]    # [bk_m, bm]  rows of X, D_in cols
     dy_blk = dy_ref[...]  # [bk_m, bs]  kept channel block of dY
-    out_ref[...] += jax.lax.dot_general(
-        x_blk,
-        dy_blk,
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    out_ref[...] += mxu_dot(x_blk, dy_blk, (((0,), (0,)), ((), ())))
 
 
 def dw_gathered(
@@ -154,7 +161,9 @@ def dw_gathered(
 # fused-im2col conv backward: patch extraction in the index maps.
 #
 # Layouts (prepared by ops.py):
-#   xg   [B*H_pad, G, W_pad, Cg]   zero-padded input, group-blocked
+#   xg   [B*H_pad, G, sw, W_ph, Cg] zero-padded input, group-blocked,
+#                                  columns split into sw phases (padded
+#                                  column i*sw + p at [p, i])
 #   dy2r [B*H_out, W_out, C_pad]   cotangent rows, channels padded to
 #                                  a block_size multiple
 #   w2k  [Kh, Kw, Cg, C_pad]       filters, OIHW -> (kh, kw, c_in, c_out)
@@ -162,8 +171,8 @@ def dw_gathered(
 # The im2col row for output position (b, oh, ow) and tap (kh, kw) lives
 # at padded-image row ``b*H_pad + oh*sh + kh*dh``, column ``ow*sw +
 # kw*dw`` — the row part is pure index-map arithmetic on a block-size-1
-# leading axis, the column part a static strided slice of the loaded
-# row. Nothing [M, C_in*Kh*Kw]-shaped ever exists in HBM.
+# leading axis, the column part a static slice of one column phase of
+# the loaded row. Nothing [M, C_in*Kh*Kw]-shaped ever exists in HBM.
 # ----------------------------------------------------------------------
 def _conv_dw_kernel(idx_ref, x_ref, dy_ref, out_ref, *, kw_dim, sw, dw_, w_out):
     s = pl.program_id(2)
@@ -172,16 +181,16 @@ def _conv_dw_kernel(idx_ref, x_ref, dy_ref, out_ref, *, kw_dim, sw, dw_, w_out):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    row = x_ref[0, 0]  # [W_pad, Cg] — padded image row oh*sh + kh*dh
-    dyb = dy_ref[0]    # [W_out, bs] — cotangent row oh, kept block j
+    dyb = dy_ref[0]  # [W_out, bs] — cotangent row oh, kept block j
     for kw in range(kw_dim):
-        lo = kw * dw_
-        xs = jax.lax.slice(
-            row, (lo, 0), (lo + sw * (w_out - 1) + 1, row.shape[1]), (sw, 1)
-        )  # [W_out, Cg] — the (kh, kw) tap of every patch in this row
-        out_ref[0, kw] += jax.lax.dot_general(
-            xs, dyb, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [Cg, bs]
+        # [W_out, Cg]: the (kh, kw) tap of every patch in padded image row
+        # oh*sh + kh*dh. Column kw*dw + sw*i sits in column phase
+        # (kw*dw) % sw at offset (kw*dw) // sw + i, so the strided tap is
+        # a contiguous static slice (Mosaic lowers no strided slices of
+        # 16-bit data, and none at all of loaded vector values).
+        off, ph = divmod(kw * dw_, sw)
+        xs = x_ref[0, 0, ph, off : off + w_out, :]
+        out_ref[0, kw] += mxu_dot(xs, dyb, (((0,), (0,)), ((), ())))  # [Cg, bs]
 
 
 def conv_dw_fused(
@@ -204,7 +213,7 @@ def conv_dw_fused(
     ``block_idx[j]``. Callers transpose to the canonical ``(c, kh, kw)``
     row order and scatter.
     """
-    s_total, g, w_pad, cg = xg.shape
+    s_total, g, sw_ph, w_ph, cg = xg.shape
     m2, w_out, c_pad = dy2r.shape
     assert m2 % h_out == 0 and c_pad % block_size == 0
     b = m2 // h_out
@@ -213,9 +222,10 @@ def conv_dw_fused(
     kb = block_idx.shape[0]
     _, sw = stride
     _, dw_ = dilation
+    assert sw_ph == sw, (xg.shape, stride)
 
     spec = specs.conv_dw_fused_spec(
-        b=b, h_pad=h_pad, w_pad=w_pad, groups=g, cg=cg, h_out=h_out,
+        b=b, h_pad=h_pad, w_pad=sw * w_ph, groups=g, cg=cg, h_out=h_out,
         w_out=w_out, c_pad=c_pad, kh_dim=kh_dim, kw_dim=kw_dim,
         stride=stride, dilation=dilation, kb=kb, block_size=block_size,
         itemsize=xg.dtype.itemsize,
@@ -254,10 +264,7 @@ def _conv_dx_kernel(
         dyrow = dy_ref[0]  # [W_out, bs]
         for kw in range(kw_dim):
             wk = w_ref[kh, kw, :, pl.dslice(j * bs, bs)]  # [Cg, bs]
-            part = jax.lax.dot_general(
-                dyrow, wk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [W_out, Cg]
+            part = mxu_dot(dyrow, wk, (((1,), (1,)), ((), ())))  # [W_out, Cg]
             w_out, cg = part.shape
             if sw == 1:
                 out_ref[0, 0, dw_ * kw : dw_ * kw + w_out, :] += part
@@ -372,12 +379,7 @@ def _mm_kernel(a_ref, b_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += jax.lax.dot_general(
-        a_ref[...],
-        b_ref[...],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    out_ref[...] += mxu_dot(a_ref[...], b_ref[...], (((1,), (0,)), ((), ())))
 
 
 def matmul(

@@ -20,6 +20,22 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _interpret_blocks(block_size: int) -> bool:
+    """``_interpret()``, refusing channel blocks Mosaic cannot tile.
+
+    A compiled kernel's channel block rides the 128-wide lane axis, so on
+    the TPU ``block_size`` must be a multiple of 128; interpret mode (CPU
+    demos and tests) takes any size.
+    """
+    interpret = _interpret()
+    if not interpret and block_size % 128:
+        raise ValueError(
+            f"Pallas block_size={block_size} cannot lower on TPU: channel "
+            "blocks must be a multiple of the 128-wide lane axis"
+        )
+    return interpret
+
+
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     size = x.shape[axis]
     pad = (-size) % mult
@@ -38,7 +54,8 @@ def dx_gathered(dy, w, block_idx, block_size: int = 128):
     dy_p = _pad_to(_pad_to(dy, 0, 128), 1, block_size)
     w_p = _pad_to(_pad_to(w, 0, 128), 1, block_size)
     out = gm.dx_gathered(
-        dy_p, w_p, block_idx, block_size=block_size, interpret=_interpret()
+        dy_p, w_p, block_idx, block_size=block_size,
+        interpret=_interpret_blocks(block_size),
     )
     return out[:m, :d_in]
 
@@ -50,7 +67,8 @@ def dw_gathered_scatter(x, dy, block_idx, n_out: int, block_size: int = 128):
     x_p = _pad_to(_pad_to(x, 0, 128), 1, 128)
     dy_p = _pad_to(_pad_to(dy, 0, 128), 1, block_size)
     compact = gm.dw_gathered(
-        x_p, dy_p, block_idx, block_size=block_size, interpret=_interpret()
+        x_p, dy_p, block_idx, block_size=block_size,
+        interpret=_interpret_blocks(block_size),
     )  # [D_in_pad, KB*block_size]
     compact = compact[:d_in]
     kb = block_idx.shape[0]
@@ -84,21 +102,27 @@ def conv_dw_fused_scatter(
     c_out, h_out = dy.shape[1], dy.shape[2]
     cg = c_in // groups
     (ph0, ph1), (pw0, pw1) = padding
-    h_pad, w_pad = h + ph0 + ph1, w_dim + pw0 + pw1
+    sw = stride[1]
+    h_pad = h + ph0 + ph1
+    w_ph = -(-(w_dim + pw0 + pw1) // sw)
     c_pad = c_out + (-c_out) % block_size
     dy2r = _dy_rows(dy, block_size)
-    # NCHW -> group-blocked padded rows [B*H_pad, G, W_pad, Cg]
-    xp = jnp.pad(x, ((0, 0), (0, 0), padding[0], padding[1]))
+    # NCHW -> group-blocked padded rows split into sw column phases,
+    # [B*H_pad, G, sw, W_ph, Cg]: padded column i*sw + p lands at [p, i]
+    # (the zero tail rounds W_pad up to sw*W_ph)
+    xp = jnp.pad(
+        x, ((0, 0), (0, 0), padding[0], (pw0, sw * w_ph - w_dim - pw0))
+    )
     xg = (
         xp.transpose(0, 2, 3, 1)
-        .reshape(b, h_pad, w_pad, groups, cg)
-        .transpose(0, 1, 3, 2, 4)
-        .reshape(b * h_pad, groups, w_pad, cg)
+        .reshape(b, h_pad, w_ph, sw, groups, cg)
+        .transpose(0, 1, 4, 3, 2, 5)
+        .reshape(b * h_pad, groups, sw, w_ph, cg)
     )
     compact = gm.conv_dw_fused(
         xg, dy2r, block_idx, kh_dim=kh, kw_dim=kw, stride=stride,
         dilation=dilation, h_out=h_out, block_size=block_size,
-        interpret=_interpret(),
+        interpret=_interpret_blocks(block_size),
     )  # [Kh, Kw, Cg, KB*bs]
     kb = block_idx.shape[0]
     d_flat = cg * kh * kw
@@ -138,7 +162,7 @@ def conv_dx_fused(
     dxp = gm.conv_dx_fused(
         dy2r, w2k, block_idx, b=b, h_pad=h_pad, w_pad=w_pad, groups=groups,
         stride=stride, dilation=dilation, block_size=block_size,
-        interpret=_interpret(),
+        interpret=_interpret_blocks(block_size),
     )  # [B*H_pad, G, W_pad, Cg]
     dx = (
         dxp.reshape(b, h_pad, groups, w_pad, cg)

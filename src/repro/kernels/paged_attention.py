@@ -22,9 +22,10 @@ Addressing rules (mirrors the write path in ``attn_apply``):
     Masked positions contribute exp-of-masked = 0 explicitly — an
     all-masked page must not inflate the denominator.
 
-GQA: q heads fold into their KV group (``[KV, S*G, D]``) so the batched
-dot contracts per KV head without materializing the repeated K/V the
-einsum path uses.
+GQA: q heads fold into their KV group (``[KV, S*G, D]``, row ``s*G + g``)
+so the batched dot contracts per KV head without materializing the
+repeated K/V the einsum path uses. The wrapper builds that layout, and
+the per-row query positions ``[S*G, 1]``, before the call.
 """
 from __future__ import annotations
 
@@ -36,6 +37,7 @@ from jax.experimental import pallas as pl
 import jax.numpy as jnp
 
 from repro.kernels import specs
+from repro.kernels.gathered_matmul import mxu_dot
 
 
 def _paged_attn_kernel(
@@ -50,27 +52,15 @@ def _paged_attn_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]  # [S, H, D]
-    s, h, d = q.shape
-    kvh = k_ref.shape[2]
-    g = h // kvh
-    sg = s * g
-    # head h = kv*G + g' -> group rows per KV head: [KV, S*G, D]
-    qg = q.reshape(s, kvh, g, d).transpose(1, 0, 2, 3).reshape(kvh, sg, d)
+    qg = q_ref[0]  # [KV, SG, D] — row r of KV head kv is q head kv*G + r%G
+    kvh, sg, _ = qg.shape
     k = k_ref[0].transpose(1, 0, 2)  # [KV, bs, D] — physical page tbl[b, j]
     v = v_ref[0].transpose(1, 0, 2).astype(jnp.float32)
-    scores = (
-        jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        * scale
-    )  # [KV, SG, bs]
+    scores = mxu_dot(qg, k, (((2,), (2,)), ((0,), (0,)))) * scale  # [KV, SG, bs]
 
     # logical positions of this page's tokens vs per-row query positions
     t_pos = j * bs_pg + jax.lax.broadcasted_iota(jnp.int32, (sg, bs_pg), 1)
-    qp = jnp.repeat(qpos_ref[0], g)  # [SG] — row r is query s = r // G
-    mask = t_pos <= qp[:, None]  # [SG, bs]
+    mask = t_pos <= qpos_ref[0]  # [SG, bs] against [SG, 1]
 
     m_prev = m_ref[...]  # [KV, SG]
     s_max = jnp.max(jnp.where(mask[None], scores, -1e30), axis=-1)
@@ -80,15 +70,14 @@ def _paged_attn_kernel(
     p = jnp.where(mask[None], jnp.exp(scores - m_new[..., None]), 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[..., None] + jax.lax.dot_general(
-        p, v, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
+    acc_ref[...] = acc_ref[...] * alpha[..., None] + mxu_dot(
+        p, v, (((2,), (1,)), ((0,), (0,)))
     )
     m_ref[...] = m_new
 
     @pl.when(j == nb - 1)
     def _done():
-        out = acc_ref[...] / l_ref[...][..., None]  # [KV, SG, D]
-        o_ref[0] = out.reshape(kvh, s, g, d).transpose(1, 0, 2, 3).reshape(s, h, d)
+        o_ref[0] = acc_ref[...] / l_ref[...][..., None]  # [KV, SG, D]
 
 
 def paged_attention(
@@ -119,15 +108,21 @@ def paged_attention(
     # tables are always valid page ids; clip defensively so a bad entry
     # can only read a wrong (causally fenced) page, never out of bounds
     tbl = jnp.clip(block_tables.reshape(-1).astype(jnp.int32), 0, n_pages - 1)
+    g = h // kvh
+    # Group-major layouts are built here, not in the kernel: Mosaic has
+    # no in-kernel reshape that moves S into the sublane axis.
+    qg = q.reshape(b, s, kvh, g, d).transpose(0, 2, 1, 3, 4).reshape(b, kvh, s * g, d)
+    qpos_rows = jnp.repeat(qpos.astype(jnp.int32), g, axis=1)[..., None]
     spec = specs.paged_attention_spec(
         b=b, s=s, h=h, d=d, n_pages=n_pages, bs_pg=bs_pg, kvh=kvh, nb=nb,
         itemsize=q.dtype.itemsize,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
             _paged_attn_kernel, bs_pg=bs_pg, nb=nb, scale=1.0 / math.sqrt(d)
         ),
         **spec.grid_spec(),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, s * g, d), jnp.float32),
         interpret=interpret,
-    )(tbl, q, k_pool, v_pool, qpos.astype(jnp.int32))
+    )(tbl, qg, k_pool, v_pool, qpos_rows)
+    return out.reshape(b, kvh, s, g, d).transpose(0, 2, 1, 3, 4).reshape(b, s, h, d)

@@ -171,21 +171,24 @@ def conv_dw_fused_spec(
     The image operand's index map holds the im2col contract checked
     against ``docs/kernels.md``: grid step ``(kh, j, s)`` reads padded
     image row ``(s // H_out) * H_pad + (s % H_out) * sh + kh * dh`` of
-    the kept block's group.
+    the kept block's group, its columns split into ``sw`` phases
+    (``W_pad`` rounded up to a multiple of ``sw``).
     """
-    sh, _ = stride
+    sh, sw = stride
     dh, _ = dilation
     m2 = b * h_out
     bpg = (c_pad // block_size) // groups
+    w_ph = -(-w_pad // sw)
     return KernelSpec(
         name="conv_dw_fused",
         grid=(kh_dim, kb, m2),
         in_specs=(
             BlockSpecInfo(
-                "xg", (b * h_pad, groups, w_pad, cg), (1, 1, w_pad, cg),
+                "xg", (b * h_pad, groups, sw, w_ph, cg), (1, 1, sw, w_ph, cg),
                 lambda kh, j, s, idx: (
                     (s // h_out) * h_pad + (s % h_out) * sh + kh * dh,
                     idx[j] // bpg,
+                    0,
                     0,
                     0,
                 ),
@@ -313,7 +316,10 @@ def paged_attention_spec(
     physical page ``tbl[b * NB + j]`` — the in-bounds proof over the
     full grid is exactly the "tables always address a real page" claim
     (the wrapper clips defensively; the checker proves the clip is a
-    no-op for well-formed tables).
+    no-op for well-formed tables). Queries and output are group-major
+    ``[B, KV, S*G, D]`` and query positions per row ``[B, S*G, 1]``, so
+    every block spans its array's trailing two dims (the TPU tiling
+    rule holds at any S and G).
     """
     sg = s * (h // kvh)
     return KernelSpec(
@@ -321,7 +327,7 @@ def paged_attention_spec(
         grid=(b, nb),
         in_specs=(
             BlockSpecInfo(
-                "q", (b, s, h, d), (1, s, h, d),
+                "q", (b, kvh, sg, d), (1, kvh, sg, d),
                 lambda bi, j, tbl: (bi, 0, 0, 0), itemsize,
             ),
             BlockSpecInfo(
@@ -333,12 +339,13 @@ def paged_attention_spec(
                 lambda bi, j, tbl: (tbl[bi * nb + j], 0, 0, 0), itemsize,
             ),
             BlockSpecInfo(
-                "qpos", (b, s), (1, s), lambda bi, j, tbl: (bi, 0), 4
+                "qpos", (b, sg, 1), (1, sg, 1),
+                lambda bi, j, tbl: (bi, 0, 0), 4,
             ),
         ),
         out_specs=(
             BlockSpecInfo(
-                "out", (b, s, h, d), (1, s, h, d),
+                "out", (b, kvh, sg, d), (1, kvh, sg, d),
                 lambda bi, j, tbl: (bi, 0, 0, 0), 4,
             ),
         ),

@@ -1,12 +1,19 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the
-# device count at first init). Test runs may shrink the placeholder
-# device pool via REPRO_DRYRUN_DEVICES (read before jax import too).
-if os.environ.get("REPRO_DRYRUN_DEVICES"):
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=" + os.environ["REPRO_DRYRUN_DEVICES"]
-    )
+
+# Before any other import: jax fixes its platform and the host device
+# count at first init. The dry run lowers on placeholder CPU devices, so
+# it pins the CPU even on a TPU host (where the chip belongs to whichever
+# process claims it first) and adds its device-count flag to the caller's
+# XLA_FLAGS rather than replacing them. REPRO_DRYRUN_DEVICES shrinks the
+# placeholder pool for tests.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(
+    filter(None, (
+        os.environ.get("XLA_FLAGS", ""),
+        "--xla_force_host_platform_device_count="
+        + os.environ.get("REPRO_DRYRUN_DEVICES", "512"),
+    ))
+)
 
 """Multi-pod dry-run driver.
 

@@ -9,20 +9,12 @@ from __future__ import annotations
 
 import jax
 
-from repro.dist import compat as _compat  # noqa: F401  (installs jax shims)
 
-
-def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions (axis_types is newer-only)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-            )
-        except TypeError:  # pragma: no cover - mid-vintage jax
-            pass
-    return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -33,8 +25,16 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over the real local devices (tests, examples)."""
-    return _make_mesh((data, model), ("data", "model"))
+    """Small mesh over the first ``data * model`` local devices.
+
+    Naming the devices makes a mesh smaller than the host (a 1x1 mesh
+    on a four-chip host) explicit: it takes device 0 onwards.
+    """
+    n = data * model
+    devices = jax.devices()
+    if n > len(devices):
+        raise ValueError(f"a {data}x{model} mesh needs {n} devices, found {len(devices)}")
+    return _make_mesh((data, model), ("data", "model"), devices=devices[:n])
 
 
 def dp_axes(mesh) -> tuple:

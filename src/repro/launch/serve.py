@@ -37,6 +37,8 @@ import jax
 import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_config
+from repro.dist import sharding as shd
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as lm
 from repro.serve import (
@@ -103,7 +105,13 @@ def run(args) -> dict:
     max_seq = args.prompt_len + args.gen + (cfg.n_patches or 0)
 
     with jax.set_mesh(mesh):
-        params = lm.init_params(cfg, rng)
+        # built on the device, placed as it is served: eager init is an
+        # op-by-op dispatch over every leaf at published widths
+        a_params = jax.eval_shape(lambda r: lm.init_params(cfg, r), rng)
+        params = jax.jit(
+            lambda r: lm.init_params(cfg, r),
+            out_shardings=shd.param_shardings(mesh, a_params),
+        )(rng)
         reqs = poisson_workload(
             cfg,
             n_requests=n_requests,
@@ -207,6 +215,7 @@ def run(args) -> dict:
 
 def main():
     args = build_parser().parse_args()
+    compile_cache.enable()
     out = run(args)
     print(f"[serve] engine={args.engine} slots={args.batch} gen={args.gen} "
           f"steps={out['steps']}")

@@ -1,5 +1,8 @@
 """Jitted train/serve step builders shared by train.py, dryrun.py, tests.
 
+``make_classifier_step`` is the ResNet classification step the examples
+and ``chip_smoke.py`` jit.
+
 ``make_train_step`` builds a gradient-accumulation (microbatched) step:
 the global batch is split into ``accum`` microbatches scanned
 sequentially with summed grads — at 123B scale the per-device activation
@@ -26,6 +29,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.policy import DENSE, PolicyLike
 from repro.models import model as lm
+from repro.models import resnet
 from repro.optim import adam
 
 
@@ -91,6 +95,32 @@ def make_train_step(
         return new_params, new_opt, metrics
 
     return train_step
+
+
+def classifier_loss(model: str, policy: PolicyLike, params, images, labels):
+    """Mean softmax cross-entropy of a ResNet on batch-statistics BatchNorm."""
+    logits = resnet.forward(model, params, images, policy)
+    logp = jax.nn.log_softmax(logits)
+    return -logp[jnp.arange(images.shape[0]), labels].mean()
+
+
+def make_classifier_step(
+    model: str, policy: PolicyLike, opt_cfg: adam.AdamConfig
+) -> Callable:
+    """(params, opt_state, images, labels) -> (params, opt_state, loss).
+
+    One ResNet classification step as the paper trains it:
+    :func:`classifier_loss`, then Adam.
+    """
+
+    def classifier_step(params, opt_state, images, labels):
+        loss_v, grads = jax.value_and_grad(classifier_loss, argnums=2)(
+            model, policy, params, images, labels
+        )
+        params, opt_state, _ = adam.apply_updates(opt_cfg, params, grads, opt_state)
+        return params, opt_state, loss_v
+
+    return classifier_step
 
 
 def make_eval_step(cfg: ModelConfig) -> Callable:

@@ -29,7 +29,10 @@ batch): loss trajectories are bit-identical at any fleet size, which
 is what lets the chaos tests assert kill → shrink → rejoin leaves the
 trajectory exactly equal to an uninterrupted run. The *distributed*
 state — membership epochs, shard plans, commit barriers — is the real
-multi-host protocol. See ``docs/distributed.md``.
+multi-host protocol. See ``docs/distributed.md``. Each rank process
+holds its host's devices, so on an accelerator the fleet runs one rank
+per host; ranks that share a TPU host are refused at start-up
+(:func:`refuse_shared_chips`), and the chaos harness runs on the CPU.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch qwen2.5-3b \
@@ -61,8 +64,8 @@ from repro.configs.registry import ARCH_IDS, get_config
 from repro.core.policy import PolicyProgram, PolicyRules, paper_default, tpu_default
 from repro.core.schedulers import make_schedule
 from repro.data.pipeline import TokenPipeline, TokenPipelineConfig
-from repro.dist import sharding as shd
 from repro.dist import compat as dist_compat
+from repro.dist import sharding as shd
 from repro.dist.fault import (
     FleetSupervisor,
     Heartbeat,
@@ -70,6 +73,7 @@ from repro.dist.fault import (
     RestartPolicy,
     StragglerSupervisor,
 )
+from repro.launch import compile_cache
 from repro.launch import steps as steps_lib
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as lm
@@ -143,6 +147,45 @@ def build_program(args, base_policy) -> PolicyProgram:
     return PolicyProgram(rules=rules, schedule=schedule)
 
 
+def init_train_state(cfg, mesh, seed: int):
+    """(params, Adam state) from ``seed``, built on the device in ``mesh``'s
+    production placement (:mod:`repro.dist.sharding`)."""
+    a_params, _ = steps_lib.abstract_state(cfg)
+    opt_sh = shd.opt_state_shardings(mesh, a_params)
+    params = jax.jit(
+        lambda r: lm.init_params(cfg, r),
+        out_shardings=shd.param_shardings(mesh, a_params),
+    )(jax.random.PRNGKey(seed))
+    zeros = jax.jit(
+        lambda p: jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), p),
+        out_shardings=opt_sh,
+    )
+    return params, adam.AdamState(
+        step=jnp.zeros((), jnp.int32), m=zeros(params), v=zeros(params)
+    )
+
+
+def refuse_shared_chips(coord_dir: str, rank: int) -> None:
+    """Fleet mode runs every rank as its own process holding its own
+    devices. An accelerator belongs to one process at a time, so ranks
+    that share a host can only run on the CPU (the chaos harness)."""
+    regs = dist_compat.registrations(coord_dir)
+    me = regs[rank]
+    peers = sorted(
+        r for r, reg in regs.items()
+        if r != rank and reg.get("host") == me.get("host")
+    )
+    if peers and any(
+        regs[r].get("platform", "cpu") != "cpu" for r in (rank, *peers)
+    ):
+        raise RuntimeError(
+            f"fleet ranks {sorted((rank, *peers))} share host "
+            f"{me.get('host')!r} on a {me.get('platform')} backend: its "
+            "chips belong to one process, so run one rank per host (or "
+            "the whole fleet with JAX_PLATFORMS=cpu)"
+        )
+
+
 def run(args) -> dict:
     cfg = get_config(args.arch)
     if args.reduced:
@@ -208,6 +251,7 @@ def run(args) -> dict:
             coord_dir, process_id=rank, num_processes=world,
             timeout_s=args.rejoin_timeout,
         )
+        refuse_shared_chips(coord_dir, rank)
         sup = FleetSupervisor(coord_dir, world, timeout_s=args.hb_timeout)
     else:
         hb = Heartbeat(os.path.join(ckpt_dir, "hb"), rank=0) if ckpt_dir else None
@@ -254,18 +298,7 @@ def run(args) -> dict:
                 commit_timeout_s=args.commit_timeout,
             )
         with jax.set_mesh(mesh):
-            params = jax.jit(
-                lambda r: lm.init_params(cfg, r), out_shardings=p_sh
-            )(jax.random.PRNGKey(args.seed))
-            opt_state = adam.AdamState(
-                step=jnp.zeros((), jnp.int32),
-                m=jax.jit(lambda p: jax.tree.map(
-                    lambda x: jnp.zeros(x.shape, jnp.float32), p),
-                    out_shardings=opt_sh)(params),
-                v=jax.jit(lambda p: jax.tree.map(
-                    lambda x: jnp.zeros(x.shape, jnp.float32), p),
-                    out_shardings=opt_sh)(params),
-            )
+            params, opt_state = init_train_state(cfg, mesh, args.seed)
             start = 0
             if ckpt_dir:
                 latest = ckpt_lib.latest_step(ckpt_dir)
@@ -348,6 +381,7 @@ def run(args) -> dict:
 
 def main():
     args = build_parser().parse_args()
+    compile_cache.enable()
     out = run(args)
     if out["final_loss"] is None:
         print("[train] nothing to do: already at the target step")

@@ -1,6 +1,9 @@
 """Unit tests for repro.dist: fit_spec, the spec rule table, fault
 tolerance edge cases, and the checkpoint paths test_system.py only
 exercises indirectly (partial shardings restore, async-save flush)."""
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -779,6 +782,39 @@ class TestProcessGroup:
         assert dist_compat.registered_ranks(d) == [0, 1]
         pgs[0].put("hello.0", "hi")
         assert pgs[1].get("hello.0", 0, timeout_s=1.0) == "hi"
+
+    def test_registrations_record_host_and_platform(self, tmp_path):
+        dist_compat.initialize(
+            str(tmp_path), process_id=0, num_processes=1, timeout_s=1.0
+        )
+        reg = dist_compat.registrations(str(tmp_path))[0]
+        assert reg["platform"] == jax.default_backend()
+        assert reg["host"] and reg["local_devices"] >= 1
+
+    @pytest.mark.parametrize(
+        "hosts,platforms,refused",
+        [
+            (("a", "a"), ("cpu", "cpu"), False),  # the chaos harness
+            (("a", "a"), ("tpu", "tpu"), True),   # ranks fight over chips
+            (("a", "a"), ("tpu", "cpu"), True),   # one fell back to the CPU
+            (("a", "b"), ("tpu", "tpu"), False),  # one rank per TPU host
+        ],
+    )
+    def test_fleet_refuses_ranks_sharing_a_tpu_host(
+        self, tmp_path, hosts, platforms, refused
+    ):
+        from repro.launch import train
+
+        os.makedirs(tmp_path / "ranks")
+        for r, (host, plat) in enumerate(zip(hosts, platforms, strict=True)):
+            with open(tmp_path / "ranks" / f"rank_{r:05d}.json", "w") as f:
+                json.dump({"pid": r, "host": host, "platform": plat}, f)
+        for rank in (0, 1):
+            if refused:
+                with pytest.raises(RuntimeError, match="one rank per host"):
+                    train.refuse_shared_chips(str(tmp_path), rank)
+            else:
+                train.refuse_shared_chips(str(tmp_path), rank)
 
     def test_initialize_times_out_on_missing_peer(self, tmp_path):
         with pytest.raises(

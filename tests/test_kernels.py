@@ -222,3 +222,21 @@ def test_paged_attention_ignores_unassigned_pages():
     out_good = ops.paged_attention(q, k_pool, v_pool, good, qpos)
     out_bad = ops.paged_attention(q, k_pool, v_pool, bad, qpos)
     np.testing.assert_allclose(out_good, out_bad, rtol=1e-6, atol=1e-6)
+
+
+def test_pallas_block_size_must_tile_lanes_on_tpu(monkeypatch):
+    """Compiled kernels carry a channel block on the 128-wide lane axis:
+    a smaller block is refused with a clear error before Mosaic sees it
+    (interpret mode, used above at block size 32, takes any size)."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    jax.clear_caches()
+    dy = jnp.ones((128, 64), jnp.float32)
+    w = jnp.ones((128, 64), jnp.float32)
+    idx = jnp.asarray([0], jnp.int32)
+    try:
+        with pytest.raises(ValueError, match="multiple of the 128-wide lane"):
+            ops.dx_gathered(dy, w, idx, block_size=32)
+        with pytest.raises(ValueError, match="multiple of the 128-wide lane"):
+            ops.dw_gathered_scatter(dy, dy, idx, n_out=64, block_size=32)
+    finally:
+        jax.clear_caches()

@@ -32,29 +32,15 @@ def _train_resnet(policy_fn, steps=30, seed=0, name="resnet18", lr=1e-3):
     opt_state = adam.init(params)
     opt_cfg = adam.AdamConfig(lr=lr)
 
-    def loss_fn(params, batch, pol):
-        logits = resnet.forward(name, params, batch["images"], pol)
-        logp = jax.nn.log_softmax(logits)
-        return -logp[jnp.arange(logits.shape[0]), batch["labels"]].mean()
-
-    @jax.jit
-    def step_dense(params, opt_state, batch):
-        lv, g = jax.value_and_grad(loss_fn)(params, batch, SsPropPolicy(0.0))
-        p, s, _ = adam.apply_updates(opt_cfg, params, g, opt_state)
-        return p, s, lv
-
-    @jax.jit
-    def step_sparse(params, opt_state, batch):
-        lv, g = jax.value_and_grad(loss_fn)(params, batch, paper_default(0.8))
-        p, s, _ = adam.apply_updates(opt_cfg, params, g, opt_state)
-        return p, s, lv
+    step_dense = jax.jit(steps_lib.make_classifier_step(name, SsPropPolicy(0.0), opt_cfg))
+    step_sparse = jax.jit(steps_lib.make_classifier_step(name, paper_default(0.8), opt_cfg))
 
     hist = []
     for i in range(steps):
         batch = jax.tree.map(jnp.asarray, pipe.batch_at(i))
         rate = policy_fn(i)
         fn = step_sparse if rate > 0 else step_dense
-        params, opt_state, lv = fn(params, opt_state, batch)
+        params, opt_state, lv = fn(params, opt_state, batch["images"], batch["labels"])
         hist.append(float(lv))
     return hist
 
@@ -235,3 +221,23 @@ class TestServing:
         out = run(args)
         assert out["generated"].shape == (2, 4)
         assert out["tokens_per_s"] > 0
+
+
+def test_compile_cache_location(monkeypatch):
+    """The entry points keep compiled programs in a fixed directory:
+    JAX_COMPILATION_CACHE_DIR when set (left to JAX, nothing set here),
+    else <checkout>/.jax_cache."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(root, ".jax_cache")
+        assert compile_cache.enable() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
