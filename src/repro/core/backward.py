@@ -40,6 +40,14 @@ import jax.numpy as jnp
 from repro.core import sparsity
 from repro.core.policy import SsPropPolicy
 
+# Name scopes of the engine's two stages. Every operation of a stage
+# carries its scope in its HLO ``op_name``, under the calling site's
+# scope, so a device trace splits each site's backward into selection
+# (importance, block importance, top-k) and contraction (the gathered or
+# masked dX / dW products and their scatter).
+SELECT_SCOPE = "ssprop_select"
+CONTRACT_SCOPE = "ssprop_contract"
+
 
 @dataclasses.dataclass
 class CanonicalForm:
@@ -190,27 +198,30 @@ def channel_sparse_backward(
     sdx, sdw = policy.sparsify_dx, policy.sparsify_dw
 
     if not policy.active or not (sdx or sdw):
-        dx, dw = op.contract_full(dy_eff)
+        with jax.named_scope(CONTRACT_SCOPE):
+            dx, dw = op.contract_full(dy_eff)
         db = dy_eff.sum(axis=reduce_axes) if has_bias else None
         return dx, dw, db
 
     key = _wrap_key(policy, key32)
-    sel = sparsity.select(
-        dy_eff,
-        policy,
-        channel_axis=ca,
-        n_shards=op.selection_shards(policy),
-        key=key,
-    )
+    with jax.named_scope(SELECT_SCOPE):
+        sel = sparsity.select(
+            dy_eff,
+            policy,
+            channel_axis=ca,
+            n_shards=op.selection_shards(policy),
+            key=key,
+        )
 
     if policy.mask_mode:
         # Reference semantics: identical selection, zeroed channels,
         # full-size contraction. The oracle every other path must match.
         # A gradient whose sparsify_* flag is off sees the raw cotangent.
-        mask = sparsity.keep_mask(dy.shape, sel.idx, channel_axis=ca, dtype=dy_eff.dtype)
-        dy_m = dy_eff * mask
-        dx = op.dx_full(dy_m if sdx else dy_eff)
-        dw = op.dw_full(dy_m if sdw else dy_eff)
+        with jax.named_scope(CONTRACT_SCOPE):
+            mask = sparsity.keep_mask(dy.shape, sel.idx, channel_axis=ca, dtype=dy_eff.dtype)
+            dy_m = dy_eff * mask
+            dx = op.dx_full(dy_m if sdx else dy_eff)
+            dw = op.dw_full(dy_m if sdw else dy_eff)
         db = (dy_m if sdw else dy_eff).sum(axis=reduce_axes) if has_bias else None
         return dx, dw, db
 
@@ -225,11 +236,28 @@ def channel_sparse_backward(
             km = sparsity.keep_mask((c,), sel.idx, channel_axis=0, dtype=dy_eff.dtype)
             db = db * km
 
+    with jax.named_scope(CONTRACT_SCOPE):
+        dx, dw = _contract_selected(policy, op, dy_eff, sel, ca, sdx, sdw)
+    return dx, dw, db
+
+
+def _contract_selected(
+    policy: SsPropPolicy,
+    op: ChannelSparseOp,
+    dy_eff: jax.Array,
+    sel: sparsity.Selection,
+    ca: int,
+    sdx: bool,
+    sdw: bool,
+) -> tuple[jax.Array, jax.Array]:
+    """(dX, dW) at the selected channels, by the first route that takes
+    the op: the TP fast path, the fused Pallas kernels, the canonical
+    Pallas kernels, else the gathered XLA contraction and scatter."""
+    c = op.c_out
     if sel.shard_idx is not None and sdx and sdw:
         fast = op.tp_contract(dy_eff, sel)
         if fast is not None:
-            dx, dw = fast
-            return dx, dw, db
+            return fast
 
     if (
         policy.use_pallas
@@ -238,8 +266,7 @@ def channel_sparse_backward(
     ):
         fused = op.fused_backward(dy_eff, sel, sdx, sdw)
         if fused is not None:
-            dx, dw = fused
-            return dx, dw, db
+            return fused
         can = op.canonical(dy_eff)
         if can is not None:
             from repro.kernels import ops as kops
@@ -254,11 +281,11 @@ def channel_sparse_backward(
                 )
             else:
                 dw2 = jnp.matmul(can.x2.T, can.dy2)
-            return can.dx_from(dx2), can.dw_from(dw2), db
+            return can.dx_from(dx2), can.dw_from(dw2)
 
     dy_k = jnp.take(dy_eff, sel.idx, axis=ca)
     if sel.valid is not None:
-        vshape = [1] * dy.ndim
+        vshape = [1] * dy_eff.ndim
         vshape[ca] = sel.k
         dy_k = dy_k * sel.valid.reshape(vshape).astype(dy_k.dtype)
     if sdx and sdw:
@@ -273,4 +300,4 @@ def channel_sparse_backward(
         dw = scatter_channels(dw_compact, sel.idx, c, op.dw_channel_axis)
     else:
         dw = op.dw_full(dy_eff)
-    return dx, dw, db
+    return dx, dw

@@ -14,10 +14,14 @@ Every call site carries a *site name* (``site=``): with a plain
 legacy global-policy path), while a resolved
 :class:`~repro.core.policy.SitePolicies` table gives each named site
 its own policy — the per-site control surface of a
-:class:`~repro.core.policy.PolicyProgram`.
+:class:`~repro.core.policy.PolicyProgram`. The name also scopes the
+call (``jax.named_scope``), so every operation of the site, forward and
+backward, carries it in its HLO ``op_name``: ``jvp(block_0/conv1)/...``
+in the forward, ``transpose(jvp(block_0/conv1))/...`` in the backward.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 
 import jax
@@ -25,6 +29,12 @@ import jax.numpy as jnp
 
 from repro.core import sparse_conv2d, sparse_dense
 from repro.core.policy import PolicyLike, policy_for
+
+
+def _scope(site: str):
+    """The site's name scope; none for an unnamed call."""
+    return jax.named_scope(site) if site else contextlib.nullcontext()
+
 
 # ----------------------------------------------------------------------
 # init helpers
@@ -41,9 +51,10 @@ def dense_init(key, d_in, d_out, *, bias=False, dtype=jnp.bfloat16, scale=None):
 
 
 def dense_apply(p, x, policy: PolicyLike, key=None, site: str = ""):
-    return sparse_dense(
-        x, p["w"], p.get("b"), policy=policy_for(policy, site), key=key
-    )
+    with _scope(site):
+        return sparse_dense(
+            x, p["w"], p.get("b"), policy=policy_for(policy, site), key=key
+        )
 
 
 def conv2d_init(key, c_out, c_in, k, *, bias=False, dtype=jnp.float32):
@@ -72,17 +83,18 @@ def conv_apply(
 ):
     """The single conv call site the CNN models share (mirrors
     :func:`dense_apply`): params dict in, ssProp-backward conv out."""
-    return sparse_conv2d(
-        x,
-        p["w"],
-        p.get("b"),
-        stride=stride,
-        padding=padding,
-        dilation=dilation,
-        groups=groups,
-        policy=policy_for(policy, site),
-        key=key,
-    )
+    with _scope(site):
+        return sparse_conv2d(
+            x,
+            p["w"],
+            p.get("b"),
+            stride=stride,
+            padding=padding,
+            dilation=dilation,
+            groups=groups,
+            policy=policy_for(policy, site),
+            key=key,
+        )
 
 
 def rmsnorm_init(d, dtype=jnp.bfloat16):

@@ -72,37 +72,43 @@ def clip_by_global_norm(grads, max_norm: float):
 def apply_updates(
     cfg: AdamConfig, params, grads, state: AdamState
 ) -> tuple[Any, AdamState, dict]:
-    """One Adam(W) step. Returns (new_params, new_state, metrics)."""
-    gn = global_norm(grads)
-    if cfg.clip_norm > 0:
-        grads, _ = clip_by_global_norm(grads, cfg.clip_norm)
-    step = state.step + 1
-    lr = lr_at(cfg, step)
-    b1, b2 = cfg.b1, cfg.b2
-    bc1 = 1 - b1 ** step.astype(jnp.float32)
-    bc2 = 1 - b2 ** step.astype(jnp.float32)
+    """One Adam(W) step. Returns (new_params, new_state, metrics).
 
-    def upd(p, g, m, v):
-        g32 = g.astype(jnp.float32)
-        m_n = b1 * m + (1 - b1) * g32
-        v_n = b2 * v + (1 - b2) * jnp.square(g32)
-        mhat = m_n / bc1
-        vhat = v_n / bc2
-        delta = mhat / (jnp.sqrt(vhat) + cfg.eps)
-        if cfg.weight_decay > 0:
-            delta = delta + cfg.weight_decay * p.astype(jnp.float32)
-        p_n = p.astype(jnp.float32) - lr * delta
-        return p_n.astype(p.dtype), m_n, v_n
+    Runs under the name scope ``adam``, so its operations carry it in
+    their HLO ``op_name`` and a device trace can tell the update apart
+    from the forward and backward.
+    """
+    with jax.named_scope("adam"):
+        gn = global_norm(grads)
+        if cfg.clip_norm > 0:
+            grads, _ = clip_by_global_norm(grads, cfg.clip_norm)
+        step = state.step + 1
+        lr = lr_at(cfg, step)
+        b1, b2 = cfg.b1, cfg.b2
+        bc1 = 1 - b1 ** step.astype(jnp.float32)
+        bc2 = 1 - b2 ** step.astype(jnp.float32)
 
-    flat_p, treedef = jax.tree.flatten(params)
-    flat_g = jax.tree.leaves(grads)
-    flat_m = jax.tree.leaves(state.m)
-    flat_v = jax.tree.leaves(state.v)
-    out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v, strict=True)]
-    new_p = jax.tree.unflatten(treedef, [o[0] for o in out])
-    new_m = jax.tree.unflatten(treedef, [o[1] for o in out])
-    new_v = jax.tree.unflatten(treedef, [o[2] for o in out])
-    return new_p, AdamState(step, new_m, new_v), {"grad_norm": gn, "lr": lr}
+        def upd(p, g, m, v):
+            g32 = g.astype(jnp.float32)
+            m_n = b1 * m + (1 - b1) * g32
+            v_n = b2 * v + (1 - b2) * jnp.square(g32)
+            mhat = m_n / bc1
+            vhat = v_n / bc2
+            delta = mhat / (jnp.sqrt(vhat) + cfg.eps)
+            if cfg.weight_decay > 0:
+                delta = delta + cfg.weight_decay * p.astype(jnp.float32)
+            p_n = p.astype(jnp.float32) - lr * delta
+            return p_n.astype(p.dtype), m_n, v_n
+
+        flat_p, treedef = jax.tree.flatten(params)
+        flat_g = jax.tree.leaves(grads)
+        flat_m = jax.tree.leaves(state.m)
+        flat_v = jax.tree.leaves(state.v)
+        out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v, strict=True)]
+        new_p = jax.tree.unflatten(treedef, [o[0] for o in out])
+        new_m = jax.tree.unflatten(treedef, [o[1] for o in out])
+        new_v = jax.tree.unflatten(treedef, [o[2] for o in out])
+        return new_p, AdamState(step, new_m, new_v), {"grad_norm": gn, "lr": lr}
 
 
 def adamw(cfg: AdamConfig | None = None) -> AdamConfig:
