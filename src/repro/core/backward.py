@@ -16,6 +16,15 @@ of the ssProp backward pipeline; they now delegate to
   5. routing to the Pallas gathered kernels when the op can lower itself
      to the canonical 2-D form (``use_pallas`` + block granularity).
 
+A site whose selection would keep every channel skips stages 2-5
+(:func:`repro.core.sparsity.keeps_every_channel`, decided from the
+static channel count, policy and shard count): block granularity keeps
+whole 128-channel blocks, so at drop rate 0.8 a 64- or 128-channel conv
+keeps its only block. Its backward is then the dense one, and it takes
+the dense contraction (the op's VJP, as at drop rate 0) rather than
+selecting an identity and paying a gathered kernel's per-block layout
+for it. Sites that drop a block keep every route below.
+
 Ops plug in through :class:`ChannelSparseOp`, providing only their
 linear algebra: the full-size contraction, the shrunk (gathered)
 contraction, and optionally a :class:`CanonicalForm` — the im2col-style
@@ -47,6 +56,9 @@ from repro.core.policy import SsPropPolicy
 # masked dX / dW products and their scatter).
 SELECT_SCOPE = "ssprop_select"
 CONTRACT_SCOPE = "ssprop_contract"
+# Nested in the contraction scope at sites whose selection keeps every
+# channel, which contract densely and select nothing.
+ALL_KEPT_SCOPE = "ssprop_all_kept"
 
 
 @dataclasses.dataclass
@@ -203,13 +215,24 @@ def channel_sparse_backward(
         db = dy_eff.sum(axis=reduce_axes) if has_bias else None
         return dx, dw, db
 
+    n_shards = op.selection_shards(policy)
+    if sparsity.keeps_every_channel(c, policy, n_shards):
+        # Selection would keep every channel (at 0.8, the one 128-channel
+        # block of a 64- or 128-channel site), so the product is the dense
+        # one: XLA's VJP, as at drop rate 0, with no selection and none of
+        # the gathered-block routes. Mask mode's mask would be all ones.
+        with jax.named_scope(CONTRACT_SCOPE), jax.named_scope(ALL_KEPT_SCOPE):
+            dx, dw = op.contract_full(dy_eff)
+        db = dy_eff.sum(axis=reduce_axes) if has_bias else None
+        return dx, dw, db
+
     key = _wrap_key(policy, key32)
     with jax.named_scope(SELECT_SCOPE):
         sel = sparsity.select(
             dy_eff,
             policy,
             channel_axis=ca,
-            n_shards=op.selection_shards(policy),
+            n_shards=n_shards,
             key=key,
         )
 

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import backward
+from repro.core import backward, sparsity
 from repro.core.policy import SsPropPolicy
 
 # frozen, so safe to share as the signature default
@@ -79,14 +79,7 @@ class _ConvOp(backward.ChannelSparseOp):
         self.c_out = w.shape[0]
 
     def selection_shards(self, policy: SsPropPolicy) -> int:
-        s = 1
-        if policy.tp_shards > 1 and self.c_out % policy.tp_shards == 0:
-            s = policy.tp_shards
-        if self.groups > 1 and (s < self.groups or s % self.groups != 0):
-            # per-group balance is a structural requirement for gathered
-            # grouped convs; it subsumes a TP degree it doesn't divide.
-            s = self.groups
-        return s
+        return sparsity.selection_shards(self.c_out, policy, self.groups)
 
     def _vjp(self, w, dy_eff):
         """VJP of the conv (over cast operands) applied to ``dy_eff``."""

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import backward
+from repro.core import backward, sparsity
 from repro.core.policy import SsPropPolicy
 
 # frozen, so safe to share as the signature default
@@ -48,9 +48,7 @@ class _DenseOp(backward.ChannelSparseOp):
         self.c_out = w.shape[1]
 
     def selection_shards(self, policy: SsPropPolicy) -> int:
-        if policy.tp_shards > 1 and self.c_out % policy.tp_shards == 0:
-            return policy.tp_shards
-        return 1
+        return sparsity.selection_shards(self.c_out, policy)
 
     def contract_full(self, dy_eff):
         return self.dx_full(dy_eff), self.dw_full(dy_eff)

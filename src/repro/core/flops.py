@@ -28,6 +28,8 @@ from __future__ import annotations
 
 from typing import TYPE_CHECKING
 
+from repro.core import sparsity
+
 if TYPE_CHECKING:
     from repro.core.policy import PolicyLike, SsPropPolicy
 
@@ -103,6 +105,13 @@ def dense_backward_flops_ssprop(
     return int(f)
 
 
+def _keeps_all(c_out: int, policy: "SsPropPolicy", groups: int = 1) -> bool:
+    """Whether the engine contracts this site densely: its selection, over
+    the shards the op balances it across, keeps every channel."""
+    n_shards = sparsity.selection_shards(c_out, policy, groups)
+    return sparsity.keeps_every_channel(c_out, policy, n_shards)
+
+
 def kept_channels(c_out: int, policy: "SsPropPolicy") -> int:
     """Output channels whose gradients the engine actually computes.
 
@@ -110,9 +119,10 @@ def kept_channels(c_out: int, policy: "SsPropPolicy") -> int:
     granularity: whole blocks, ``keep_count`` blocks × ``block_size``
     channels, capped at ``C`` — an upper bound when the ragged tail
     block is among the kept (its phantom slots are masked at runtime but
-    the contraction is sized for the full block).
+    the contraction is sized for the full block). A site that keeps every
+    channel contracts densely, at ``C``.
     """
-    if not policy.active:
+    if sparsity.keeps_every_channel(c_out, policy):
         return c_out
     if policy.granularity == "channel":
         return policy.keep_count(c_out)
@@ -130,12 +140,13 @@ def gather_width(
     is sized for whole blocks. Sharded selection (TP / grouped convs)
     keeps ``k_loc`` channels per shard with a shard-local block size —
     mirrored from :func:`repro.core.sparsity.shard_select_width` so the
-    tables count exactly what the backward traces.
+    tables count exactly what the backward traces. A site that keeps
+    every channel contracts densely, at ``C``.
     """
+    if sparsity.keeps_every_channel(c_out, policy, n_shards):
+        return c_out
     if n_shards > 1:
-        from repro.core.sparsity import shard_select_width
-
-        k_loc, _ = shard_select_width(c_out, policy, n_shards)
+        k_loc, _ = sparsity.shard_select_width(c_out, policy, n_shards)
         return n_shards * k_loc
     if policy.granularity == "channel":
         return policy.keep_count(c_out)
@@ -169,7 +180,7 @@ def conv_backward_flops_policy(
     m = bt * h_out * w_out
     n = c_in * k * k
     sdx, sdw = policy.sparsify_dx, policy.sparsify_dw
-    if not policy.active or not (sdx or sdw):
+    if not (sdx or sdw) or _keeps_all(c_out, policy):
         return conv_backward_flops(bt, h_out, w_out, c_in, c_out, k)
     kept = kept_channels(c_out, policy)
     # Eq. 6 decomposes per output element as 2N (dX) + 2N (dW) + 1 (db);
@@ -193,7 +204,7 @@ def dense_backward_flops_policy(
 ) -> int:
     """Dense analogue of :func:`conv_backward_flops_policy` (K=1 conv)."""
     sdx, sdw = policy.sparsify_dx, policy.sparsify_dw
-    if not policy.active or not (sdx or sdw):
+    if not (sdx or sdw) or _keeps_all(d_out, policy):
         return dense_backward_flops(m, d_in, d_out, bias=bias)
     kept = kept_channels(d_out, policy)
     if policy.use_pallas and policy.granularity == "block":
@@ -220,7 +231,8 @@ def _conv_fused_route(
 
     Replicates :meth:`repro.core.conv._ConvOp.fused_backward`'s gate:
     structural conditions (``fuse_im2col``, a real patch buffer to fuse
-    away, whole blocks per group) plus the traffic-model min. The
+    away, whole blocks per group, a selection that drops something, else
+    the engine contracts densely) plus the traffic-model min. The
     auditor needs the routing decision statically to predict which
     kernels appear in the jaxpr.
     """
@@ -233,6 +245,8 @@ def _conv_fused_route(
     ):
         return False
     if groups > 1 and c_out % (groups * policy.block_size) != 0:
+        return False
+    if _keeps_all(c_out, policy, groups):
         return False
     fus = conv_backward_bytes_policy(
         bt, h_out, w_out, c_in, c_out, k, policy, fused=True, groups=groups
@@ -271,27 +285,23 @@ def conv_backward_contraction_bounds(
     stride-1 'SAME'-ish ``H_out + K - 1`` (the bytes model's
     convention); pass the true padded height for exact bounds.
 
-    The invariant the hook-consistency test pins: on every non-fused
-    route, ``conv_backward_flops_policy == lo + db_term + M*C_out`` for
-    ``groups == 1``.
+    A site whose selection keeps every channel (:func:`_keeps_all`)
+    contracts densely, as at drop rate 0: ``2 * full_side``.
+
+    On every other non-fused route, ``conv_backward_flops_policy == lo
+    + db_term + M*C_out`` for ``groups == 1``; on the dense one the
+    importance term ``M*C_out`` is not paid.
     """
     m = bt * h_out * w_out
     cg = c_in // groups
     n_g = cg * k * k
     full_side = 2 * m * n_g * c_out
     sdx, sdw = policy.sparsify_dx, policy.sparsify_dw
-    if not policy.active or not (sdx or sdw) or policy.mask_mode:
+    n_shards = sparsity.selection_shards(c_out, policy, groups)
+    dense = policy.mask_mode or sparsity.keeps_every_channel(c_out, policy, n_shards)
+    if dense or not (sdx or sdw):
         return (2 * full_side, 2 * full_side)
 
-    # selection sharding mirrors _ConvOp.selection_shards: per-group
-    # balance is structural; it subsumes a TP degree it doesn't divide.
-    n_shards = (
-        policy.tp_shards
-        if policy.tp_shards > 1 and c_out % policy.tp_shards == 0
-        else 1
-    )
-    if groups > 1 and (n_shards < groups or n_shards % groups != 0):
-        n_shards = groups
     width = gather_width(c_out, policy, n_shards)
     gathered_side = 2 * m * n_g * width
 
@@ -350,7 +360,8 @@ def dense_backward_contraction_bounds(
     :func:`repro.core.backward.channel_sparse_backward` +
     :class:`repro.core.dense._DenseOp`:
 
-    * inactive / mask_mode: two full ``2*M*D_in*D_out`` matmuls,
+    * inactive / mask_mode / a selection that keeps every channel:
+      two full ``2*M*D_in*D_out`` matmuls,
     * TP fast path (``tp_shards`` divides ``D_out``, both sides
       sparsified): two unpadded gathered einsums — *before* the Pallas
       branch, so padding never applies,
@@ -362,15 +373,11 @@ def dense_backward_contraction_bounds(
     """
     full_side = 2 * m * d_in * d_out
     sdx, sdw = policy.sparsify_dx, policy.sparsify_dw
-    if not policy.active or not (sdx or sdw) or policy.mask_mode:
+    n_shards = sparsity.selection_shards(d_out, policy)
+    dense = policy.mask_mode or sparsity.keeps_every_channel(d_out, policy, n_shards)
+    if dense or not (sdx or sdw):
         return (2 * full_side, 2 * full_side)
 
-    # selection sharding mirrors _DenseOp.selection_shards
-    n_shards = (
-        policy.tp_shards
-        if policy.tp_shards > 1 and d_out % policy.tp_shards == 0
-        else 1
-    )
     width = gather_width(d_out, policy, n_shards)
     gathered_side = 2 * m * d_in * width
 
@@ -431,33 +438,18 @@ def conv_backward_bytes_policy(
       ``[M, N]`` buffer exists anywhere. Traffic scales with the kept
       block count, so sparsity cuts bytes as well as FLOPs.
 
-    ``fused=None`` routes exactly like the engine: the fused model when
-    the policy's Pallas/fuse_im2col path applies to this conv and it
-    moves fewer bytes, the materializing model otherwise (this min is
-    the gate :meth:`repro.core.conv._ConvOp.fused_backward` applies).
+    ``fused=None`` routes exactly like the engine (:func:`_conv_fused_route`):
+    the fused model when the policy's Pallas/fuse_im2col path applies to
+    this conv and it moves fewer bytes (the gate
+    :meth:`repro.core.conv._ConvOp.fused_backward` applies), the
+    materializing model otherwise, as at drop rate 0 for a site that
+    keeps every channel and contracts densely.
     Geometry is counted at stride 1 / 'SAME'-ish padding
     (``H_pad = H_out + K - 1``) — walkers don't carry strides, and both
     regimes use the same approximation.
     """
     if fused is None:
-        mat = conv_backward_bytes_policy(
-            bt, h_out, w_out, c_in, c_out, k, policy,
-            fused=False, itemsize=itemsize, groups=groups,
-        )
-        if not (
-            policy.active
-            and policy.use_pallas
-            and policy.granularity == "block"
-            and policy.fuse_im2col
-            and k > 1
-        ):
-            return mat
-        fus = conv_backward_bytes_policy(
-            bt, h_out, w_out, c_in, c_out, k, policy,
-            fused=True, itemsize=itemsize, groups=groups,
-        )
-        return min(mat, fus)
-
+        fused = _conv_fused_route(bt, h_out, w_out, c_in, c_out, k, policy, groups)
     parts = conv_backward_bytes_breakdown(
         bt, h_out, w_out, c_in, c_out, k, policy, fused=fused, groups=groups
     )

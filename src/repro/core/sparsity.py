@@ -258,6 +258,36 @@ def shard_select_width(
     return max(1, policy.keep_count(c) // n_shards), 1
 
 
+def selection_shards(c: int, policy: SsPropPolicy, groups: int = 1) -> int:
+    """How many contiguous channel groups selection balances over (1 =
+    global top-k): the policy's TP degree where it divides ``C``, and a
+    grouped conv's ``groups`` where that degree does not cover them (a
+    gathered grouped conv stays well-formed only under per-group
+    balance)."""
+    n = policy.tp_shards if policy.tp_shards > 1 and c % policy.tp_shards == 0 else 1
+    if groups > 1 and (n < groups or n % groups != 0):
+        n = groups
+    return n
+
+
+def keeps_every_channel(c: int, policy: SsPropPolicy, n_shards: int = 1) -> bool:
+    """Whether :func:`select` over ``C`` channels keeps all of them.
+
+    Channel granularity keeps ``keep_count(C)`` of ``C`` channels, block
+    granularity ``keep_count(C)`` of ``ceil(C / block_size)`` blocks, and
+    sharded selection ``k_loc`` of each shard's ``C / n_shards``
+    channels (:func:`shard_select_width`). When nothing is dropped the
+    selection is the identity, so the backward engine contracts densely
+    instead. Every input is static, so under jit this is a Python bool.
+    """
+    if n_shards > 1:
+        return shard_select_width(c, policy, n_shards)[0] == c // n_shards
+    k = policy.keep_count(c)
+    if policy.granularity == "channel":
+        return k == c
+    return k == -(-c // policy.block_size)
+
+
 def select_indices_per_shard(
     dy2: jax.Array,
     policy: SsPropPolicy,

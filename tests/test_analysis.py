@@ -57,16 +57,34 @@ def _fresh_trace_cache():
 
 
 class TestConvAuditGrid:
+    # 32 output channels keep every block of 128 or 32 channels, so the
+    # block policies contract densely there; 256 drop a block on every
+    # gathered and Pallas route (but two groups of one 128-channel block
+    # keep both)
+    @pytest.mark.parametrize("c_out", [32, 256])
     @pytest.mark.parametrize("pname,policy", _policies())
     @pytest.mark.parametrize("groups", [1, 2])
     @pytest.mark.parametrize("bwd_dtype", ["", "bfloat16"])
-    def test_measured_equals_bounds(self, pname, policy, groups, bwd_dtype):
+    def test_measured_equals_bounds(self, pname, policy, groups, bwd_dtype, c_out):
         policy = dataclasses.replace(policy, bwd_dtype=bwd_dtype)
         rep = Report("t")
         savings.audit_conv_site(
-            rep, "site", 2, 8, 8, 16, 32, 3, policy, groups=groups
+            rep, "site", 2, 8, 8, 16, c_out, 3, policy, groups=groups
         )
         assert not rep.errors(), [f.message for f in rep.errors()]
+
+    @pytest.mark.parametrize("c_out,k", [(64, 3), (128, 3), (128, 1)])
+    def test_site_that_keeps_every_channel_audits_dense(self, c_out, k):
+        # ResNet-18's 64- and 128-channel sites at 0.8 (one block kept
+        # of one): no Pallas kernel, no gathered product, the dense count
+        pol = dataclasses.replace(tpu_default(0.8), use_pallas=True)
+        rep = Report("t")
+        counts = savings.audit_conv_site(rep, "site", 2, 8, 8, 64, c_out, k, pol)
+        assert not rep.errors(), [f.message for f in rep.errors()]
+        dense = ftab.conv_backward_contraction_bounds(2, 8, 8, 64, c_out, k, DENSE)
+        assert (counts.flops_lo, counts.flops_hi) == dense
+        assert ftab.gather_width(c_out, pol) == ftab.kept_channels(c_out, pol) == c_out
+        assert not ftab._conv_fused_route(2, 8, 8, 64, c_out, k, pol, 1)
 
     def test_strided_site_audits_via_stride1_twin(self):
         # the probe is stride-1 by construction; the tables carry no
@@ -83,12 +101,14 @@ class TestConvAuditGrid:
 
 
 class TestDenseAuditGrid:
+    # 128 outputs are one 128-channel block, kept whole at 0.8
+    @pytest.mark.parametrize("d_out", [128, 256])
     @pytest.mark.parametrize("pname,policy", _policies())
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-    def test_measured_equals_bounds(self, pname, policy, dtype):
+    def test_measured_equals_bounds(self, pname, policy, dtype, d_out):
         rep = Report("t")
         counts = savings.audit_dense_site(
-            rep, "site", 64, 128, 256, policy, dtype=dtype
+            rep, "site", 64, 128, d_out, policy, dtype=dtype
         )
         assert not rep.errors(), [f.message for f in rep.errors()]
         # dense bounds are a point interval on every route
@@ -224,6 +244,20 @@ class TestPallasTraffic:
         rep = Report("t")
         pallas_check.check_conv_fused_site(rep, "site", 2, 8, 8, 32, 64, 3, pol)
         assert not rep.errors(), [f.message for f in rep.errors()]
+
+    def test_resnet18_fused_route_leaves_sites_that_keep_every_channel(self):
+        # at 0.8 in 128-channel blocks only the 256- and 512-channel 3x3
+        # sites run the fused kernels, so only they are audited for traffic
+        from repro.models import resnet
+
+        pol = dataclasses.replace(tpu_default(0.8), use_pallas=True)
+        fused = {
+            site: c_out
+            for site, c_in, c_out, k, h, w in resnet.iter_conv_shapes("resnet18", (3, 32, 32))
+            if ftab._conv_fused_route(128, h, w, c_in, c_out, k, pol, 1)
+        }
+        assert sorted(set(fused.values())) == [256, 512]
+        assert len(fused) == 8
 
     def test_paged_attention_geometry(self):
         rep = Report("t")

@@ -105,9 +105,11 @@ class TestConvParityGrid:
 
     @pytest.mark.parametrize("granularity,bwd_dtype", CFGS)
     def test_conv_tp_shards_gather_equals_mask(self, granularity, bwd_dtype):
-        g1 = _conv_grads(_pol(granularity, bwd_dtype, tp_shards=4), 1, 1, 1, 1)
+        # 64 channels: each of 4 shards holds two 8-channel blocks and
+        # keeps one (16 channels, one block, would keep every channel)
+        g1 = _conv_grads(_pol(granularity, bwd_dtype, tp_shards=4), 1, 1, 1, 1, c_out=64)
         g2 = _conv_grads(
-            _pol(granularity, bwd_dtype, mask=True, tp_shards=4), 1, 1, 1, 1
+            _pol(granularity, bwd_dtype, mask=True, tp_shards=4), 1, 1, 1, 1, c_out=64
         )
         for name, a, r in zip(("dx", "dw", "db"), g1, g2, strict=True):
             np.testing.assert_allclose(
@@ -125,8 +127,12 @@ class TestDenseParityGrid:
     @pytest.mark.parametrize("granularity,bwd_dtype", CFGS)
     @pytest.mark.parametrize("tp_shards", [0, 4])
     def test_gather_equals_mask_oracle(self, granularity, bwd_dtype, tp_shards):
-        g1 = _dense_grads(_pol(granularity, bwd_dtype, tp_shards=tp_shards))
-        g2 = _dense_grads(_pol(granularity, bwd_dtype, mask=True, tp_shards=tp_shards))
+        # 64 outputs: with 4 shards each drops one of its two 8-channel
+        # blocks (32 outputs, one block a shard, would keep every channel)
+        g1 = _dense_grads(_pol(granularity, bwd_dtype, tp_shards=tp_shards), d_out=64)
+        g2 = _dense_grads(
+            _pol(granularity, bwd_dtype, mask=True, tp_shards=tp_shards), d_out=64
+        )
         for name, a, r in zip(("dx", "dw", "db"), g1, g2, strict=True):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(r), err_msg=name, **_tols(bwd_dtype)
@@ -230,17 +236,154 @@ class TestPallasParity:
             np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-3, atol=1e-4)
 
     def test_conv_pallas_grouped_indivisible_falls_back(self, monkeypatch):
-        # c_out=16 with groups=2 needs whole 8-channel blocks per group;
-        # block_size=16 can't split block-diagonally -> framework VJP,
-        # still exact.
+        # c_out=48 with groups=2 holds 24 channels a group; block_size=16
+        # can't split block-diagonally -> framework VJP of the gathered
+        # conv (each group keeps 8 of its 24), still exact.
         calls = self._spy(monkeypatch, ("conv_dx_fused", "conv_dw_fused_scatter"))
         pol = _pol("block", "", block_size=16, use_pallas=True)
         ref = _pol("block", "", block_size=16, mask=True)
-        g1 = _conv_grads(pol, 1, 1, 1, 2)
-        g2 = _conv_grads(ref, 1, 1, 1, 2)
+        assert not sparsity.keeps_every_channel(48, pol, 2)
+        g1 = _conv_grads(pol, 1, 1, 1, 2, c_out=48)
+        g2 = _conv_grads(ref, 1, 1, 1, 2, c_out=48)
         assert calls["conv_dx_fused"] == 0 and calls["conv_dw_fused_scatter"] == 0
         for a, r in zip(g1, g2, strict=True):
             np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=2e-4, atol=1e-5)
+
+
+class TestAllKeptSites:
+    """A site whose selection keeps every channel (one 128-channel block at
+    0.8) contracts densely: no selection, no Pallas kernel, and the same
+    gradients as the route that selects the identity and as the float32
+    dense VJP."""
+
+    POLICY = dataclasses.replace(tpu_default(0.8), use_pallas=True)
+    # (c_out, groups, tp_shards, block_size): whole blocks per group or
+    # shard where the selected route can reach the Pallas kernels
+    SITES = [
+        (64, 1, 0, 128),
+        (128, 1, 0, 128),
+        (128, 2, 0, 64),
+        (64, 2, 0, 128),
+        (128, 1, 4, 32),
+    ]
+
+    def _conv(self, c_out, groups, tp_shards, block_size):
+        from repro.core import conv
+
+        pol = dataclasses.replace(
+            self.POLICY, tp_shards=tp_shards, block_size=block_size
+        )
+        ks = jax.random.split(jax.random.PRNGKey(7), 3)
+        # large enough that the fused kernels move fewer bytes than the
+        # patch buffers, so the selected route takes them
+        x = jax.random.normal(ks[0], (2, 32, 16, 16))
+        w = jax.random.normal(ks[1], (c_out, 32 // groups, 3, 3)) * 0.2
+        dy = jax.random.normal(ks[2], (2, c_out, 16, 16))
+        pads = ((1, 1), (1, 1))
+        op = conv._ConvOp(x, w, (1, 1), pads, (1, 1), groups, pol)
+
+        def ref(dy):
+            with jax.default_matmul_precision("highest"):
+                return conv._ConvOp(x, w, (1, 1), pads, (1, 1), groups, pol).contract_full(dy)
+
+        return pol, op, dy, ref
+
+    @staticmethod
+    def _close(name, a, r):
+        # float32 accumulation: a sum of n products errs by about
+        # eps * sqrt(n) of the gradient's scale, not of each element
+        a, r = np.asarray(a), np.asarray(r)
+        np.testing.assert_allclose(a, r, rtol=2e-4, atol=1e-6 * np.abs(r).max(), err_msg=name)
+
+    def _selected_route(self, pol, op, dy):
+        """The route the engine takes when it selects: here the identity."""
+        from repro.core import backward
+
+        sel = sparsity.select(dy, pol, channel_axis=1, n_shards=op.selection_shards(pol))
+        return backward._contract_selected(pol, op, dy, sel, 1, True, True)
+
+    @pytest.mark.parametrize("bias", [False, True])
+    @pytest.mark.parametrize("c_out,groups,tp_shards,block_size", SITES)
+    def test_conv_equals_selected_route_and_dense_vjp(
+        self, c_out, groups, tp_shards, block_size, bias
+    ):
+        from repro.core import backward
+
+        pol, op, dy, ref = self._conv(c_out, groups, tp_shards, block_size)
+        assert sparsity.keeps_every_channel(c_out, pol, op.selection_shards(pol))
+        dx, dw, db = backward.channel_sparse_backward(pol, op, dy, has_bias=bias)
+        sx, sw = self._selected_route(pol, op, dy)
+        rx, rw = ref(dy)
+        for name, a, b, r in (("dx", dx, sx, rx), ("dw", dw, sw, rw)):
+            self._close(name, a, b)
+            self._close(name, a, r)
+        if bias:
+            self._close("db", db, dy.sum((0, 2, 3)))
+        else:
+            assert db is None
+
+    @pytest.mark.parametrize("d_out,tp_shards", [(64, 0), (128, 0), (128, 4)])
+    def test_dense_equals_selected_route_and_dense_vjp(self, d_out, tp_shards):
+        from repro.core import backward, dense
+
+        pol = dataclasses.replace(self.POLICY, tp_shards=tp_shards)
+        ks = jax.random.split(jax.random.PRNGKey(8), 3)
+        x = jax.random.normal(ks[0], (32, 48))
+        w = jax.random.normal(ks[1], (48, d_out)) * 0.2
+        dy = jax.random.normal(ks[2], (32, d_out))
+        op = dense._DenseOp(x, w, pol)
+        assert sparsity.keeps_every_channel(d_out, pol, op.selection_shards(pol))
+        dx, dw, db = backward.channel_sparse_backward(pol, op, dy, has_bias=True)
+        sx, sw = self._selected_route(pol, op, dy)
+        with jax.default_matmul_precision("highest"):
+            rx, rw = dy @ w.T, x.T @ dy
+        for name, a, b, r in (("dx", dx, sx, rx), ("dw", dw, sw, rw)):
+            self._close(name, a, b)
+            self._close(name, a, r)
+        self._close("db", db, dy.sum(0))
+
+    @pytest.mark.parametrize(
+        "c_out,groups,tp_shards,block_size", [s for s in SITES if s != (64, 2, 0, 128)]
+    )
+    def test_selected_route_reaches_the_fused_kernels(
+        self, monkeypatch, c_out, groups, tp_shards, block_size
+    ):
+        # the comparison above is against the fused Pallas route wherever
+        # whole blocks allow it (64 channels in two groups of 32 cannot
+        # hold a 128-channel block, so that case selects through XLA)
+        from repro.kernels import ops as kops
+
+        calls = []
+        real = kops.conv_dx_fused
+        monkeypatch.setattr(
+            kops, "conv_dx_fused", lambda *a, **kw: calls.append(1) or real(*a, **kw)
+        )
+        pol, op, dy, _ = self._conv(c_out, groups, tp_shards, block_size)
+        self._selected_route(pol, op, dy)
+        assert calls
+
+    @pytest.mark.parametrize(
+        "c_out,mask,kept_all",
+        [(64, False, True), (128, False, True), (128, True, True),
+         (256, False, False), (256, True, False)],
+    )
+    def test_route_in_jaxpr(self, c_out, mask, kept_all):
+        # an engaged site holds no kernel and no top-k; a site that drops
+        # a block (256 channels = 2 blocks, 1 kept) still selects and, off
+        # mask mode, runs the fused kernel
+        pol = dataclasses.replace(self.POLICY, mask_mode=mask)
+        x = jnp.zeros((2, 64, 16, 16))
+        w = jnp.zeros((c_out, 64, 3, 3))
+
+        def loss(x, w):
+            return jnp.sum(sparse_conv2d(x, w, padding=1, policy=pol) ** 2)
+
+        text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, w))
+        assert ("top_k" in text) == (not kept_all)
+        if kept_all or mask:
+            assert "pallas_call" not in text
+        else:
+            assert "conv_dx_fused" in text and "pallas_call" in text
 
 
 class TestRaggedTailRegression:
