@@ -59,6 +59,10 @@ CONTRACT_SCOPE = "ssprop_contract"
 # Nested in the contraction scope at sites whose selection keeps every
 # channel, which contract densely and select nothing.
 ALL_KEPT_SCOPE = "ssprop_all_kept"
+# Nested in the contraction scope on the TP-local route
+# (``ChannelSparseOp.tp_contract``), taken where selection balances over
+# the shards of a sharded output axis.
+TP_SCOPE = "ssprop_tp"
 
 
 @dataclasses.dataclass
@@ -278,7 +282,8 @@ def _contract_selected(
     Pallas kernels, else the gathered XLA contraction and scatter."""
     c = op.c_out
     if sel.shard_idx is not None and sdx and sdw:
-        fast = op.tp_contract(dy_eff, sel)
+        with jax.named_scope(TP_SCOPE):
+            fast = op.tp_contract(dy_eff, sel)
         if fast is not None:
             return fast
 

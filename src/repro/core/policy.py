@@ -465,6 +465,25 @@ class ResolvedProgram:
     def policies_for_step(self, step: int) -> SitePolicies:
         return self.at_scale(self.schedule.scale(step))
 
+    def with_tp_shards(self, shards) -> "ResolvedProgram":
+        """Each site's policy told over how many shards its output
+        channels are split: ``tp_shards = shards(site)`` where that is
+        above 1 (selection then balances over the shards, and the dense
+        backward takes its TP-local route), 0 where the channels are
+        whole."""
+
+        def told(site: str, p: SsPropPolicy) -> SsPropPolicy:
+            n = shards(site)
+            return dataclasses.replace(p, tp_shards=n if n > 1 else 0)
+
+        return dataclasses.replace(
+            self,
+            sites=SitePolicies(
+                tuple((n, told(n, p)) for n, p in self.sites.entries),
+                default=self.sites.default,
+            ),
+        )
+
     def peak(self) -> SitePolicies:
         """The fully-on table (scale 1): what a sparse epoch runs."""
         return self.at_scale(1.0)

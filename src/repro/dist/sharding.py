@@ -26,6 +26,20 @@ _COL_PARALLEL = ("q", "k", "v", "up", "gate", "in_proj")
 _ROW_PARALLEL = ("o", "down", "out_proj")
 
 
+def output_shards(mesh, site: str) -> int:
+    """How many ways ``mesh`` splits the output channels of the projection
+    at ``site`` (a site name such as ``layer_3/attn/q``): the ``model``
+    degree for a column-parallel kernel, which :func:`param_specs` shards
+    on its last dim, else 1. Row-parallel kernels (``o``, ``down``) split
+    their input; routed experts (``moe/up``) their expert axis.
+    """
+    parts = site.split("/")
+    routed = len(parts) > 1 and parts[-2] == "moe"
+    if parts[-1] in _COL_PARALLEL and not routed:
+        return int(dict(mesh.shape).get("model", 1))
+    return 1
+
+
 def _keystr(path) -> str:
     return jax.tree_util.keystr(path)
 

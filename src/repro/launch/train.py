@@ -61,7 +61,14 @@ import jax.numpy as jnp
 
 from repro.checkpoint import ckpt as ckpt_lib
 from repro.configs.registry import ARCH_IDS, get_config
-from repro.core.policy import PolicyProgram, PolicyRules, paper_default, tpu_default
+from repro.configs.base import ModelConfig
+from repro.core.policy import (
+    PolicyProgram,
+    PolicyRules,
+    ResolvedProgram,
+    paper_default,
+    tpu_default,
+)
 from repro.core.schedulers import make_schedule
 from repro.data.pipeline import TokenPipeline, TokenPipelineConfig
 from repro.dist import compat as dist_compat
@@ -147,15 +154,22 @@ def build_program(args, base_policy) -> PolicyProgram:
     return PolicyProgram(rules=rules, schedule=schedule)
 
 
+def init_sharded_params(cfg, mesh, seed: int):
+    """The weights from ``seed``, built on the device in ``mesh``'s
+    production placement (:mod:`repro.dist.sharding`)."""
+    a_params, _ = steps_lib.abstract_state(cfg)
+    return jax.jit(
+        lambda r: lm.init_params(cfg, r),
+        out_shardings=shd.param_shardings(mesh, a_params),
+    )(jax.random.PRNGKey(seed))
+
+
 def init_train_state(cfg, mesh, seed: int):
     """(params, Adam state) from ``seed``, built on the device in ``mesh``'s
     production placement (:mod:`repro.dist.sharding`)."""
     a_params, _ = steps_lib.abstract_state(cfg)
     opt_sh = shd.opt_state_shardings(mesh, a_params)
-    params = jax.jit(
-        lambda r: lm.init_params(cfg, r),
-        out_shardings=shd.param_shardings(mesh, a_params),
-    )(jax.random.PRNGKey(seed))
+    params = init_sharded_params(cfg, mesh, seed)
     zeros = jax.jit(
         lambda p: jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), p),
         out_shardings=opt_sh,
@@ -186,18 +200,53 @@ def refuse_shared_chips(coord_dir: str, rank: int) -> None:
         )
 
 
-def run(args) -> dict:
+@dataclasses.dataclass
+class Trainer:
+    """What :func:`run` trains with, built apart from the loop (:func:`build`)
+    so that a benchmark drives the same step on the same state."""
+
+    cfg: ModelConfig
+    mesh: jax.sharding.Mesh
+    pipe: TokenPipeline
+    program: PolicyProgram
+    resolved: ResolvedProgram
+    opt_cfg: adam.AdamConfig
+    seed: int
+    steps: dict = dataclasses.field(default_factory=dict)
+
+    def step_for(self, step: int):
+        """The jitted step (params and optimizer state donated) that
+        ``step`` runs. One executable per schedule bucket (paper: 2 for
+        epoch_bar); the step's SitePolicies table is the cache key, so
+        per-site programs cost no extra retraces beyond the buckets."""
+        table = self.resolved.policies_for_step(step)
+        if table not in self.steps:
+            fn = steps_lib.make_train_step(self.cfg, table, self.opt_cfg)
+            # the new state keeps the placement it came in: a step whose
+            # outputs were left to the compiler returned some of them
+            # placed otherwise, and every later call compiled again
+            p_sh = shd.param_shardings(self.mesh, steps_lib.abstract_state(self.cfg)[0])
+            state_sh = (p_sh, adam.AdamState(shd.replicated(self.mesh), p_sh, p_sh))
+            self.steps[table] = jax.jit(
+                fn, donate_argnums=(0, 1), out_shardings=(*state_sh, None)
+            )
+        return self.steps[table]
+
+
+def build(args) -> Trainer:
+    """The model, mesh, data pipeline, policy program and optimizer that
+    ``args`` (:func:`build_parser`) describe. Every site whose output
+    channels the mesh splits (column-parallel projections) selects per
+    shard (``tp_shards`` = the ``model`` degree); the others globally."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     if getattr(args, "no_scan_layers", False):
         cfg = dataclasses.replace(cfg, scan_layers=False)
     mesh = make_host_mesh(args.data_mesh, args.model_mesh)
-
     pipe = TokenPipeline(
         TokenPipelineConfig(cfg.vocab, args.seq_len, args.global_batch, args.seed)
     )
-
     base_policy = (
         paper_default(args.drop_rate)
         if args.granularity == "channel"
@@ -205,24 +254,20 @@ def run(args) -> dict:
     )
     program = build_program(args, base_policy)
     sites, depth = lm.site_names(cfg)
-    resolved = program.resolve(sites, depth=depth)
+    resolved = program.resolve(sites, depth=depth).with_tp_shards(
+        lambda site: shd.output_shards(mesh, site)
+    )
     opt_cfg = adam.AdamConfig(lr=args.lr, clip_norm=1.0, total_steps=args.steps)
+    return Trainer(cfg, mesh, pipe, program, resolved, opt_cfg, args.seed)
+
+
+def run(args) -> dict:
+    trainer = build(args)
+    cfg, mesh, pipe, program = trainer.cfg, trainer.mesh, trainer.pipe, trainer.program
 
     a_params, _ = steps_lib.abstract_state(cfg)
     p_sh = shd.param_shardings(mesh, a_params)
     opt_sh = shd.opt_state_shardings(mesh, a_params)
-
-    # one compiled executable per schedule bucket (paper: 2 for epoch_bar);
-    # the per-step SitePolicies table is the cache key, so per-site
-    # programs cost no extra retraces beyond the schedule's buckets.
-    step_cache = {}
-
-    def get_step(step: int):
-        table = resolved.policies_for_step(step)
-        if table not in step_cache:
-            fn = steps_lib.make_train_step(cfg, table, opt_cfg)
-            step_cache[table] = jax.jit(fn, donate_argnums=(0, 1))
-        return step_cache[table]
 
     ckpt_dir = args.ckpt_dir
     rank = getattr(args, "rank", 0)
@@ -326,7 +371,7 @@ def run(args) -> dict:
                     raise RuntimeError("injected failure (fault-tolerance test)")
                 if args.step_delay > 0:
                     time.sleep(args.step_delay)
-                fn = get_step(step)
+                fn = trainer.step_for(step)
                 rate = program.schedule.rate(step)
                 batch = jax.tree.map(jnp.asarray, pipe.batch_at(step))
                 t0 = time.time()

@@ -174,6 +174,11 @@ def _gqa_out(probs, v):
     return jnp.einsum("bhst,bthd->bshd", probs, v_full.astype(jnp.float32))
 
 
+# Name scope of attention's own work (scores, mask, softmax and the
+# probabilities' product with V), apart from its projections' sites.
+ATTN_CORE_SCOPE = "attn_core"
+
+
 def masked_attention(
     q: jax.Array,
     k: jax.Array,
@@ -215,7 +220,10 @@ def masked_attention(
     kv_positions = jnp.arange(t)
 
     def body(carry, args):
-        qc, qp = args
+        with jax.named_scope(ATTN_CORE_SCOPE):
+            return carry, _core(*args)
+
+    def _core(qc, qp):
         scores = _gqa_scores(qc, k) * scale  # [B,H,qc,T] fp32
         if seq_shard_hint:
             # §Perf iter 3: keep decode scores sharded on the KV-seq dim
@@ -240,7 +248,7 @@ def masked_attention(
                 mask &= kv_positions[None, :] < kv_len
             scores = jnp.where(mask[None, None], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1)
-        return carry, _gqa_out(probs, v)
+        return _gqa_out(probs, v)
 
     _, outs = jax.lax.scan(body, None, (qs, qpos_chunks))
     out = outs.transpose(1, 0, 2, 3, 4).reshape(b, nchunks * q_chunk, h, d)

@@ -67,13 +67,13 @@ def _embed_inputs(cfg, params, batch):
     return x
 
 
-def forward(
-    cfg: ModelConfig,
-    params,
-    batch: dict[str, jax.Array],
-    policy: PolicyLike = DENSE,
-) -> tuple[jax.Array, jax.Array]:
-    """Full-sequence forward. Returns (logits fp32 [B, S, V], aux_loss)."""
+# Name scope of the loss head: the tied unembedding, log-softmax and
+# cross-entropy.
+LOSS_SCOPE = "lm_loss"
+
+
+def _final_hidden(cfg: ModelConfig, params, batch, policy: PolicyLike):
+    """The final norm's output over the text positions, and the aux loss."""
     x = _embed_inputs(cfg, params, batch)
     aux = jnp.zeros((), jnp.float32)
     if cfg.family == "encdec":
@@ -85,8 +85,18 @@ def forward(
     x = layers.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     if cfg.family == "vlm":
         x = x[:, cfg.n_patches :]
-    logits = layers.unembed_apply(params["embed"], x, valid=cfg.vocab)
-    return logits, aux
+    return x, aux
+
+
+def forward(
+    cfg: ModelConfig,
+    params,
+    batch: dict[str, jax.Array],
+    policy: PolicyLike = DENSE,
+) -> tuple[jax.Array, jax.Array]:
+    """Full-sequence forward. Returns (logits fp32 [B, S, V], aux_loss)."""
+    x, aux = _final_hidden(cfg, params, batch, policy)
+    return layers.unembed_apply(params["embed"], x, valid=cfg.vocab), aux
 
 
 def loss_fn(
@@ -95,13 +105,16 @@ def loss_fn(
     batch: dict[str, jax.Array],
     policy: PolicyLike = DENSE,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
-    """Next-token cross-entropy (+0.01·MoE aux)."""
-    logits, aux = forward(cfg, params, batch, policy)
+    """Next-token cross-entropy (+0.01·MoE aux), the loss head under the
+    ``lm_loss`` scope."""
+    x, aux = _final_hidden(cfg, params, batch, policy)
     targets = batch["targets"]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    mask = batch.get("loss_mask", jnp.ones_like(targets, jnp.float32))
-    loss = (nll * mask).sum() / jnp.clip(mask.sum(), 1.0)
+    with jax.named_scope(LOSS_SCOPE):
+        logits = layers.unembed_apply(params["embed"], x, valid=cfg.vocab)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        mask = batch.get("loss_mask", jnp.ones_like(targets, jnp.float32))
+        loss = (nll * mask).sum() / jnp.clip(mask.sum(), 1.0)
     total = loss + 0.01 * aux
     return total, {"ce": loss, "aux": aux}
 

@@ -3,7 +3,9 @@ carries the site's name scope in its HLO ``op_name``, forward and
 backward; the sparse backward's selection and contraction carry
 ``ssprop_select`` and ``ssprop_contract`` on every route, and a site whose
 selection would keep every channel contracts under
-``ssprop_contract/ssprop_all_kept`` and selects nothing; the optimizer's
+``ssprop_contract/ssprop_all_kept`` and selects nothing; the TP-local
+route contracts under ``ssprop_contract/ssprop_tp``; attention's own work
+carries ``attn_core`` and the LM loss head ``lm_loss``; the optimizer's
 update carries ``adam``. Scopes are metadata: they change no instruction.
 """
 from __future__ import annotations
@@ -142,3 +144,28 @@ def test_scopes_change_no_instruction():
         return collections.Counter(_OPCODE.findall(_conv_grad_hlo(policy, site=site)))
 
     assert count(SITE) == count("")
+
+
+def test_lm_step_scopes_attention_loss_and_tp_route():
+    """The decoder's training step names attention's own work
+    (``attn_core``), the loss head (``lm_loss``) and, at sites selecting
+    per shard, the TP-local contraction (``ssprop_contract/ssprop_tp``)."""
+    from repro.configs.registry import get_config
+    from repro.core.policy import PolicyProgram
+    from repro.models import model as lm
+
+    cfg = get_config("qwen2.5-3b").reduced(d_ff=1024)
+    sites, depth = lm.site_names(cfg)
+    table = PolicyProgram.single(tpu_default(0.8)).resolve(sites, depth=depth)
+    table = table.with_tp_shards(lambda s: 4 if s.endswith("mlp/up") else 1).peak()
+    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    step = steps.make_train_step(cfg, table, adam.AdamConfig())
+    names = _OP_NAME.findall(
+        _hlo(step, params, adam.init(params), {"tokens": tokens, "targets": tokens})
+    )
+    parts = [set(re.split(r"[/()]", n)) for n in names]
+    assert any("attn_core" in p for p in parts)
+    assert any("lm_loss" in p for p in parts)
+    tp = [n for n in names if "ssprop_contract/ssprop_tp/" in n]
+    assert tp and all("mlp/up" in n for n in tp)
