@@ -1,18 +1,11 @@
-"""Kernel-level before/after table for the Pallas fusion pass.
+"""Kernel-level before/after table for the paged-attention kernel.
 
-One row per fusion, each an A/B against the path it replaces:
-
-* **fused im2col** — whole-model backward HBM traffic with the
-  materializing canonical path (real ``X2``/``dX2`` patch buffers)
-  vs the engine's fused routing, from the analytic bytes model
-  (``repro.core.flops.conv_backward_bytes_policy``). Asserted: fused
-  never moves more bytes (the traffic model is also the routing gate).
 * **paged attention** — the serving engine with the per-layer
   ``pool[block_tables]`` gather vs the in-place Pallas kernel.
   Asserted: token-for-token parity and the 3x->1x pool-bytes model.
-* **micro parity cells** — the fused kernels against their materialized
-  oracles on one concrete small geometry, numerically (asserted) and
-  wall-clock (informational: interpret-mode timings don't predict TPU).
+* **micro parity cell** — the kernel against its gather oracle on one
+  concrete small geometry, numerically (asserted) and wall-clock
+  (informational: interpret-mode timings don't predict TPU).
 
 Emits ``name,us_per_call,derived`` CSV like every table and writes
 ``BENCH_kernels.json`` next to this file.
@@ -20,7 +13,6 @@ Emits ``name,us_per_call,derived`` CSV like every table and writes
 Run:  PYTHONPATH=src python benchmarks/kernels_table.py [--smoke]
 """
 import argparse
-import dataclasses
 import json
 import os
 import sys
@@ -35,42 +27,6 @@ import numpy as np
 from benchmarks.common import emit, time_fn
 
 ATTN_ARCH = "qwen2.5-3b"
-
-
-def conv_micro_rows() -> list:
-    """Fused vs materializing backward on one concrete layer, asserted
-    numerically equal (to fp32 tolerance) and timed informational."""
-    from repro.core.conv import sparse_conv2d
-    from repro.core.policy import tpu_default
-
-    pol = dataclasses.replace(tpu_default(0.5), block_size=4, use_pallas=True)
-    key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (2, 8, 16, 16), jnp.float32)
-    w = jax.random.normal(key, (16, 8, 3, 3), jnp.float32) * 0.1
-    grads, times = {}, {}
-    for label, fuse in (("fused", True), ("materializing", False)):
-        p = dataclasses.replace(pol, fuse_im2col=fuse)
-
-        def f(x, w):
-            return sparse_conv2d(x, w, padding=1, policy=p).sum()
-
-        g = jax.jit(jax.grad(f, argnums=(0, 1)))
-        grads[label] = jax.block_until_ready(g(x, w))
-        times[label] = time_fn(g, x, w, iters=3, warmup=1)
-    dx_err = float(jnp.max(jnp.abs(grads["fused"][0] - grads["materializing"][0])))
-    dw_err = float(jnp.max(jnp.abs(grads["fused"][1] - grads["materializing"][1])))
-    assert dx_err < 1e-4 and dw_err < 1e-4, (
-        f"fused im2col diverged from materialized oracle: "
-        f"dx_err={dx_err} dw_err={dw_err}"
-    )
-    return [{
-        "kernel": "conv_backward_fused_im2col",
-        "shape": "b2c8x16k3/bs4/drop0.5",
-        "dx_err": dx_err,
-        "dw_err": dw_err,
-        "fused_us": times["fused"],
-        "materializing_us": times["materializing"],
-    }]
 
 
 def attn_micro_row() -> dict:
@@ -116,26 +72,9 @@ def attn_micro_row() -> dict:
 
 
 def run(json_path=None, smoke=False):
-    from benchmarks import roofline, serve_latency
+    from benchmarks import serve_latency
 
     rows = []
-    for row in roofline.iter_fusion_rows():
-        rows.append({"kernel": "fused_im2col", **row})
-        emit(
-            f"kernels/fused_im2col/{row['arch']}",
-            row["fused_s"] * 1e6,
-            f"mat_bytes={row['materializing_bytes']};"
-            f"fused_bytes={row['fused_bytes']};"
-            f"bytes_saved={row['bytes_saved']:.3f}",
-        )
-    for row in conv_micro_rows():
-        rows.append(row)
-        emit(
-            f"kernels/{row['kernel']}",
-            row["fused_us"],
-            f"mat_us={row['materializing_us']:.1f};"
-            f"dx_err={row['dx_err']:.2e};dw_err={row['dw_err']:.2e}",
-        )
     arow = attn_micro_row()
     rows.append(arow)
     emit(

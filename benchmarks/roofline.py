@@ -307,19 +307,12 @@ def _conv_sites(model: str, image):
     return resnet.iter_conv_shapes(model, image)
 
 
-def _conv_bytes(model: str, batch: int, image, policy, fused=None) -> int:
-    """Whole-model backward HBM traffic (conv_backward_bytes_policy).
-
-    ``fused=None`` counts what the engine actually routes (the traffic
-    model picks fused vs materializing per site); False/True force one
-    regime for the A/B rows.
-    """
+def _conv_bytes(model: str, batch: int, image, policy) -> int:
+    """Whole-model backward HBM traffic (conv_backward_bytes_policy)."""
     from repro.core import flops as F
 
     return sum(
-        F.conv_backward_bytes_site(
-            batch, h, w, ci, co, k, policy, site, fused=fused
-        )
+        F.conv_backward_bytes_site(batch, h, w, ci, co, k, policy, site)
         for site, ci, co, k, h, w in _conv_sites(model, image)
     )
 
@@ -347,8 +340,7 @@ def conv_roofline_row(model: str, batch: int, image, policy_name: str):
 
     Compute comes from the policy-aware Eq. 6/9 model
     (``conv_backward_flops_policy``): block granularity counts whole
-    kept blocks and the Pallas path counts its 128-aligned tile padding,
-    so the block/Pallas rows genuinely reflect what the unified backward
+    kept blocks, so the block rows reflect what the unified backward
     engine executes, not the nominal channel top-k rate. The memory term
     is the policy-aware bytes-moved model
     (``conv_backward_bytes_policy`` summed over the model's conv sites)
@@ -382,78 +374,6 @@ def iter_conv_rows():
     for model, batch, image in _CONV_CELLS:
         for pname in _CONV_POLICY_NAMES:
             yield conv_roofline_row(model, batch, image, pname)
-
-
-def conv_fusion_row(model: str, batch: int, image, policy_name: str):
-    """Before/after HBM traffic of the fused-im2col backward.
-
-    'Before' forces the materializing canonical path (real ``X2``/``dX2``
-    patch buffers at every site); 'after' is the engine's actual routing
-    (the traffic model picks fused or materializing per site). The
-    assertion is the fusion's contract: the routed path never moves more
-    bytes than materializing, because routing falls back wherever fusing
-    would lose (1x1 convs, tiny-``C_in`` stems, degenerate outputs).
-    """
-    policy = _conv_policy(model, policy_name)
-    mat = _conv_bytes(model, batch, image, policy, fused=False)
-    fus = _conv_bytes(model, batch, image, policy, fused=None)
-    assert fus <= mat, (
-        f"fused im2col moves more bytes than materializing for {model}/"
-        f"{policy_name}: {fus} > {mat} — the routing gate is broken"
-    )
-    return {
-        "arch": model,
-        "shape": f"b{batch}x{image[1]}",
-        "policy": policy_name,
-        "status": "ok",
-        "materializing_bytes": mat,
-        "fused_bytes": fus,
-        "materializing_s": mat / HBM_BW,
-        "fused_s": fus / HBM_BW,
-        "bytes_saved": 1.0 - fus / mat,
-    }
-
-
-# fusion A/B only makes sense where the engine has a fused path to take
-_FUSION_POLICY_NAMES = ("ssprop_block_pallas",)
-
-
-def _measured_fusion_cell():
-    """One measured wall-clock A/B of fuse_im2col on a small layer.
-
-    Interpret-mode Pallas timings do not predict TPU wall-clock — the
-    asserted quantity is the analytic bytes model above; this row exists
-    so the harness records that both variants actually execute, and the
-    timing is informational.
-    """
-    import time
-
-    from repro.core.conv import sparse_conv2d
-
-    pol = dataclasses.replace(tpu_default(0.5), block_size=4, use_pallas=True)
-    key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (2, 8, 16, 16), jnp.float32)
-    w = jax.random.normal(key, (16, 8, 3, 3), jnp.float32) * 0.1
-    out = {}
-    for label, fuse in (("fused", True), ("materializing", False)):
-        p = dataclasses.replace(pol, fuse_im2col=fuse)
-
-        def f(x, w):
-            return sparse_conv2d(x, w, padding=1, policy=p).sum()
-
-        g = jax.jit(jax.grad(f, argnums=(0, 1)))
-        jax.block_until_ready(g(x, w))  # compile
-        t0 = time.perf_counter()
-        jax.block_until_ready(g(x, w))
-        out[label] = time.perf_counter() - t0
-    return out
-
-
-def iter_fusion_rows():
-    """All fused-vs-materializing A/B rows — shared by run()/main()."""
-    for model, batch, image in _CONV_CELLS:
-        for pname in _FUSION_POLICY_NAMES:
-            yield conv_fusion_row(model, batch, image, pname)
 
 
 def _load_dryrun(arch, shape_name, mesh, policy):
@@ -527,17 +447,6 @@ def run():
             f"dom={row['dominant']};saved={row['saved']:.3f};"
             f"mem_s={row['memory_s']:.4f};bytes={row['bytes_moved']}",
         )
-    # fused-im2col before/after: HBM traffic with vs without the patch
-    # buffers, the quantity the fusion pass exists to cut.
-    for row in iter_fusion_rows():
-        emit(
-            f"roofline/conv_fusion/{row['arch']}/{row['policy']}",
-            row["fused_s"] * 1e6,
-            f"mat_s={row['materializing_s']:.4f};"
-            f"bytes_saved={row['bytes_saved']:.3f};"
-            f"mat_bytes={row['materializing_bytes']};"
-            f"fused_bytes={row['fused_bytes']}",
-        )
 
 
 def main():
@@ -548,10 +457,6 @@ def main():
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--conv", action="store_true",
                     help="emit the conv-model rows (policy-aware FLOPs)")
-    ap.add_argument("--fused", action="store_true",
-                    help="emit fused-vs-materializing im2col A/B rows "
-                    "(asserts fused bytes <= materializing) plus one "
-                    "measured wall-clock cell")
     ap.add_argument("--lm-sites", action="store_true",
                     help="emit jaxpr-derived per-site projection rows "
                     "(measured backward interval, replacing the 6ND "
@@ -588,31 +493,14 @@ def main():
             with open(args.json, "w") as f:
                 json.dump(rows, f, indent=1)
         return
-    if args.conv or args.fused:
-        if args.conv:
-            for row in iter_conv_rows():
-                rows.append(row)
-                print(
-                    f"{row['arch']:10s} {row['shape']:8s} {row['policy']:20s} "
-                    f"comp={row['compute_s']:.4f}s mem={row['memory_s']:.4f}s "
-                    f"bytes={row['bytes_moved']/1e9:.2f}GB "
-                    f"saved={row['saved']:.3f} dom={row['dominant']}"
-                )
-        if args.fused:
-            for row in iter_fusion_rows():
-                rows.append(row)
-                print(
-                    f"{row['arch']:10s} {row['shape']:8s} {row['policy']:20s} "
-                    f"mat={row['materializing_bytes']/1e9:.2f}GB "
-                    f"fused={row['fused_bytes']/1e9:.2f}GB "
-                    f"({row['materializing_s']:.4f}s -> {row['fused_s']:.4f}s, "
-                    f"bytes_saved={row['bytes_saved']:.3f})"
-                )
-            t = _measured_fusion_cell()
-            rows.append({"arch": "micro", "policy": "measured", **t})
+    if args.conv:
+        for row in iter_conv_rows():
+            rows.append(row)
             print(
-                f"measured (interpret, informational): "
-                f"fused={t['fused']:.3f}s materializing={t['materializing']:.3f}s"
+                f"{row['arch']:10s} {row['shape']:8s} {row['policy']:20s} "
+                f"comp={row['compute_s']:.4f}s mem={row['memory_s']:.4f}s "
+                f"bytes={row['bytes_moved']/1e9:.2f}GB "
+                f"saved={row['saved']:.3f} dom={row['dominant']}"
             )
         if args.json:
             with open(args.json, "w") as f:

@@ -13,10 +13,11 @@ Phases on one chip (the default):
 * **train** - ResNet-18 on the paper's CIFAR-10 task (3x32x32, 10
   classes, batch 128, Adam 2e-4; synthetic ``ImagePipeline`` data from
   ``--seed``), with the TPU policy (128-channel blocks, drop rate 0.8,
-  Pallas kernels on) under the 2-epoch bar schedule cut to a short epoch,
+  ``use_pallas`` on) under the 2-epoch bar schedule cut to a short epoch,
   so the dense and the sparse executable both run. Checks finite losses,
-  a ``tpu_custom_call`` in the sparse executable, and on one batch the
-  Pallas-route gradients against the ``mask_mode`` oracle.
+  no ``tpu_custom_call`` in the sparse executable (every conv site that
+  drops a block takes XLA's gathered conv), and on one batch the
+  gathered-route gradients against the ``mask_mode`` oracle.
 * **serve** - the Pallas paged-attention kernel against the engine's
   gather path at qwen2.5-3b heads (S=1 and S=8), then
   ``repro.launch.serve.run`` for qwen2.5-3b at published width (random
@@ -68,8 +69,8 @@ from repro.models import model as lm  # noqa: E402
 from repro.models import resnet  # noqa: E402
 from repro.optim import adam  # noqa: E402
 
-# Pallas-route gradients against the mask_mode oracle: max over parameter
-# leaves of ||g_pallas - g_mask|| / ||g_mask||, both computed at "highest"
+# Gathered-route gradients against the mask_mode oracle: max over parameter
+# leaves of ||g_gather - g_mask|| / ||g_mask||, both computed at "highest"
 # matmul precision. The two routes contract in different orders and tiles,
 # so they agree to f32 rounding; a wrong or missing kept block moves a
 # leaf by the block's whole share (tens of percent).
@@ -154,10 +155,10 @@ def train_phase(seed: int) -> None:
         'custom_call_target="tpu_custom_call"'
     )
     log(f"train: sparse executable holds {n_custom} tpu_custom_call ops")
-    if n_custom == 0:
-        fail("train: the sparse executable holds no tpu_custom_call")
+    if n_custom != 0:
+        fail(f"train: the sparse executable holds {n_custom} tpu_custom_call ops, want 0")
 
-    # Pallas route vs the mask_mode oracle on one batch
+    # the gathered route vs the mask_mode oracle on one batch
     batch = pipe.batch_at(0)
     x, y = jnp.asarray(batch["images"]), jnp.asarray(batch["labels"])
     sparse = policy.with_rate(policy.target_rate)
@@ -169,16 +170,16 @@ def train_phase(seed: int) -> None:
         ))(params)
 
     with jax.default_matmul_precision("highest"):
-        g_pallas, g_mask = grads(sparse), grads(oracle)
+        g_gather, g_mask = grads(sparse), grads(oracle)
     errs = jax.tree.map(
         lambda a, b: float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-30)),
-        g_pallas, g_mask,
+        g_gather, g_mask,
     )
     worst = max(jax.tree.leaves(errs))
     log(f"train: gather vs mask gradients: max relative error {worst!r} "
         f"(tolerance {GRAD_RTOL})")
     if not worst <= GRAD_RTOL:
-        fail(f"train: Pallas gradients differ from the mask oracle by {worst}")
+        fail(f"train: gathered gradients differ from the mask oracle by {worst}")
 
 
 def attention_check(seed: int) -> None:

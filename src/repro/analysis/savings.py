@@ -7,20 +7,20 @@ and counts its contractions with :mod:`repro.analysis.jaxpr_walk`. The
 measured ``(lo, hi)`` interval must equal
 :func:`repro.core.flops.conv_backward_contraction_bounds` /
 ``dense_backward_contraction_bounds`` **exactly** — those tables model
-every route the engine takes, including Pallas tile padding and the
-im2col materialization convs, so any daylight between the two means the
-paper-facing savings numbers are dishonest and the audit errors.
+every route the engine takes, including the Pallas tile padding of dense
+sites (conv sites take XLA's gathered conv, never a kernel), so any
+daylight between the two means the paper-facing savings numbers are
+dishonest and the audit errors.
 
 The legacy Eq.-9 tables (``conv_backward_flops_policy`` et al., what
 ``benchmarks/roofline.py`` historically multiplied out) are compared as
-a *sanity band*: they deliberately omit the im2col materialization and
-the fused-dX padded sweep, so the audit only warns when they drift more
-than 2x from the measured interval — block-rounding and bookkeeping
-tolerance, not a contract.
+a *sanity band*: they count kept channels rather than the engine's
+gathered width, so the audit only warns when they drift more than 2x
+from the measured interval — block-rounding and bookkeeping tolerance,
+not a contract.
 
 Probes are geometry-exact: convs are traced at stride 1 with
-``(K-1)``-total padding so ``H_in == H_out`` and the padded image height
-is the ``H_out + K - 1`` the bounds table assumes. The analytic tables
+``(K-1)``-total padding so ``H_in == H_out``. The analytic tables
 carry no stride parameter, so a strided site audits through its
 stride-1 twin with the same output geometry — same M, N, routing and
 padding, hence the same backward contraction cost the tables model.
@@ -170,8 +170,7 @@ def audit_conv_site(
         bt, h_out, w_out, c_in, c_out, k, policy, groups, dtype
     )
     lo, hi = ftab.conv_backward_contraction_bounds(
-        bt, h_out, w_out, c_in, c_out, k, policy,
-        groups=groups, h_pad=h_out + k - 1,
+        bt, h_out, w_out, c_in, c_out, k, policy, groups=groups
     )
     if (counts.flops_lo, counts.flops_hi) != (lo, hi):
         report.add(

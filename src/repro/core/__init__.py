@@ -16,8 +16,8 @@ The paper's contribution as a composable JAX module:
 * :mod:`repro.core.dense`      — ``sparse_dense``: matmul adapter over
   the engine (custom_vjp).
 * :mod:`repro.core.conv`       — ``sparse_conv2d``: convolution adapter
-  over the engine; lowers to im2col canonical form for the Pallas
-  gathered kernels (``kernels/im2col.py``).
+  over the engine; a site that drops a block contracts through XLA's
+  gathered conv, with or without ``use_pallas``.
 * :mod:`repro.core.flops`      — the paper's FLOPs model (Eq. 6-11) and
   the policy-aware counts (block rounding, Pallas tile padding).
 * :mod:`repro.core.policy`     — the policy program surface:

@@ -12,9 +12,13 @@ of the ssProp backward pipeline; they now delegate to
      over a full-size contraction),
   4. the gather of kept channels and the scatter of compact dW/db back
      into full-size zero buffers (``.add``-based, so clamped tail
-     duplicates cannot overwrite the last real channel),
+     duplicates cannot overwrite the last real channel; whole kept
+     blocks move as slabs, :meth:`ChannelSparseOp.take_kept`),
   5. routing to the Pallas gathered kernels when the op can lower itself
      to the canonical 2-D form (``use_pallas`` + block granularity).
+     Only dense sites offer that form; a conv site that drops a block
+     contracts through XLA's gathered conv, which on the TPU beats the
+     Pallas conv kernels at every ResNet-18 site.
 
 A site whose selection would keep every channel skips stages 2-5
 (:func:`repro.core.sparsity.keeps_every_channel`, decided from the
@@ -27,7 +31,7 @@ for it. Sites that drop a block keep every route below.
 
 Ops plug in through :class:`ChannelSparseOp`, providing only their
 linear algebra: the full-size contraction, the shrunk (gathered)
-contraction, and optionally a :class:`CanonicalForm` — the im2col-style
+contraction, and optionally a :class:`CanonicalForm` — the 2-D
 ``X2 [M, D_flat] / W2 [D_flat, C_out] / dY2 [M, C_out]`` view that the
 Pallas ``dx_gathered`` / ``dw_gathered_scatter`` kernels consume — and a
 TP fast path for comm-free sharded gathers.
@@ -63,6 +67,10 @@ ALL_KEPT_SCOPE = "ssprop_all_kept"
 # (``ChannelSparseOp.tp_contract``), taken where selection balances over
 # the shards of a sharded output axis.
 TP_SCOPE = "ssprop_tp"
+# Nested in the contraction scope on the generic gathered route: the
+# gather of the kept channels, the op's gathered contraction and the dW
+# scatter (every conv site that drops a block takes it).
+GATHERED_SCOPE = "ssprop_gathered"
 
 
 @dataclasses.dataclass
@@ -73,7 +81,7 @@ class CanonicalForm:
     rows of ``x2``/``dy2`` aligned (same (batch, position) ordering).
     ``dx_from`` / ``dw_from`` lift the canonical gradients — dX2
     ``[M, D_flat]`` and full-size dW2 ``[D_flat, C_out]`` — back to the
-    op's native shapes (dense: reshape; conv: col2im / OIHW reshape).
+    op's native shapes (dense: a reshape).
     """
 
     x2: jax.Array
@@ -100,6 +108,10 @@ class ChannelSparseOp:
     c_out: int
     channel_axis: int
     dw_channel_axis: int
+    # Gather and scatter whole kept blocks as slabs where selection kept
+    # whole blocks (:meth:`take_kept`). Dense sites keep the channel form
+    # until the slab form is measured on the sharded LM step they run in.
+    block_slabs: bool = True
 
     def __init__(self, policy: SsPropPolicy):
         self.policy = policy
@@ -132,7 +144,8 @@ class ChannelSparseOp:
     ) -> tuple[jax.Array, jax.Array]:
         """(dX, compact dW) from the gathered cotangent ``dy_k`` (kept
         channels only, phantom slots already zeroed). The compact dW has
-        ``sel.k`` channels on ``dw_channel_axis``; the engine scatters."""
+        ``sel.k`` channels on ``dw_channel_axis``; :meth:`scatter_dw`
+        scatters it."""
         raise NotImplementedError
 
     def contract_gathered_dx(self, dy_k: jax.Array, sel) -> jax.Array:
@@ -144,19 +157,58 @@ class ChannelSparseOp:
         """Gathered compact dW alone (mixed ``sparsify_dx=False`` path)."""
         return self.contract_gathered(dy_k, sel)[1]
 
+    def _whole_blocks(self, sel: sparsity.Selection) -> bool:
+        """Selection kept whole blocks of ``block_size`` channels (no
+        ragged tail), in the order of ``sel.idx``, and the op moves them
+        as slabs."""
+        return (
+            self.block_slabs
+            and sel.block_idx is not None
+            and self.c_out % self.policy.block_size == 0
+        )
+
+    def take_kept(self, a: jax.Array, sel: sparsity.Selection, axis: int) -> jax.Array:
+        """``a`` at the kept output channels along ``axis``: whole blocks
+        where selection kept them, which XLA moves as slabs and not
+        channel by channel, else the kept channel indices."""
+        if not self._whole_blocks(sel):
+            return jnp.take(a, sel.idx, axis=axis)
+        axis = axis % a.ndim
+        bs, shape = self.policy.block_size, a.shape
+        split = shape[:axis] + (shape[axis] // bs, bs) + shape[axis + 1:]
+        kept = jnp.take(a.reshape(split), sel.block_idx, axis=axis)
+        return kept.reshape(shape[:axis] + (sel.k,) + shape[axis + 1:])
+
+    def gather_kept(self, dy_eff: jax.Array, sel: sparsity.Selection) -> jax.Array:
+        """The cotangent at the kept channels (``sel.k`` of them on
+        ``channel_axis``), phantom slots of a ragged tail zeroed."""
+        ca = self.channel_axis % dy_eff.ndim
+        dy_k = self.take_kept(dy_eff, sel, ca)
+        if sel.valid is not None:
+            vshape = [1] * dy_eff.ndim
+            vshape[ca] = sel.k
+            dy_k = dy_k * sel.valid.reshape(vshape).astype(dy_k.dtype)
+        return dy_k
+
+    def scatter_dw(self, dw_compact: jax.Array, sel: sparsity.Selection) -> jax.Array:
+        """The compact dW of the gathered contraction, scattered into a
+        full-size zero dW (whole kept blocks as slabs where
+        :meth:`take_kept` gathers them so)."""
+        if not self._whole_blocks(sel):
+            return scatter_channels(
+                dw_compact, sel.idx, self.c_out, self.dw_channel_axis
+            )
+        ax = self.dw_channel_axis % dw_compact.ndim
+        bs, shape = self.policy.block_size, dw_compact.shape
+        lead, rest = shape[:ax], shape[ax + 1:]
+        full = jnp.zeros(lead + (self.c_out // bs, bs) + rest, dw_compact.dtype)
+        at = (slice(None),) * ax + (sel.block_idx,)
+        full = full.at[at].add(dw_compact.reshape(lead + (-1, bs) + rest))
+        return full.reshape(lead + (self.c_out,) + rest)
+
     def canonical(self, dy_eff: jax.Array) -> CanonicalForm | None:
         """The 2-D lowering for the Pallas gathered kernels, or None when
         the op cannot (or should not) lower itself."""
-        return None
-
-    def fused_backward(
-        self, dy_eff: jax.Array, sel: sparsity.Selection, sdx: bool, sdw: bool
-    ) -> tuple[jax.Array, jax.Array] | None:
-        """Optional fully-fused Pallas path: (dX, dW) in native shapes and
-        accumulation dtype, or None to fall through to the canonical-form
-        kernels. Checked first in the Pallas branch — ops that can fuse
-        their data-layout transform into the kernels' index maps (conv
-        im2col) skip the materialized canonical buffers entirely."""
         return None
 
     def tp_contract(
@@ -264,7 +316,7 @@ def channel_sparse_backward(
             db = db * km
 
     with jax.named_scope(CONTRACT_SCOPE):
-        dx, dw = _contract_selected(policy, op, dy_eff, sel, ca, sdx, sdw)
+        dx, dw = _contract_selected(policy, op, dy_eff, sel, sdx, sdw)
     return dx, dw, db
 
 
@@ -273,13 +325,12 @@ def _contract_selected(
     op: ChannelSparseOp,
     dy_eff: jax.Array,
     sel: sparsity.Selection,
-    ca: int,
     sdx: bool,
     sdw: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """(dX, dW) at the selected channels, by the first route that takes
-    the op: the TP fast path, the fused Pallas kernels, the canonical
-    Pallas kernels, else the gathered XLA contraction and scatter."""
+    the op: the TP fast path, the canonical Pallas kernels, else the
+    gathered XLA contraction and scatter."""
     c = op.c_out
     if sel.shard_idx is not None and sdx and sdw:
         with jax.named_scope(TP_SCOPE):
@@ -292,9 +343,6 @@ def _contract_selected(
         and policy.granularity == "block"
         and sel.block_idx is not None
     ):
-        fused = op.fused_backward(dy_eff, sel, sdx, sdw)
-        if fused is not None:
-            return fused
         can = op.canonical(dy_eff)
         if can is not None:
             from repro.kernels import ops as kops
@@ -311,21 +359,15 @@ def _contract_selected(
                 dw2 = jnp.matmul(can.x2.T, can.dy2)
             return can.dx_from(dx2), can.dw_from(dw2)
 
-    dy_k = jnp.take(dy_eff, sel.idx, axis=ca)
-    if sel.valid is not None:
-        vshape = [1] * dy_eff.ndim
-        vshape[ca] = sel.k
-        dy_k = dy_k * sel.valid.reshape(vshape).astype(dy_k.dtype)
-    if sdx and sdw:
-        dx, dw_compact = op.contract_gathered(dy_k, sel)
-    elif sdx:
-        dx = op.contract_gathered_dx(dy_k, sel)
-        dw_compact = None
-    else:
-        dx = op.dx_full(dy_eff)
-        dw_compact = op.contract_gathered_dw(dy_k, sel)
-    if sdw:
-        dw = scatter_channels(dw_compact, sel.idx, c, op.dw_channel_axis)
-    else:
-        dw = op.dw_full(dy_eff)
+    with jax.named_scope(GATHERED_SCOPE):
+        dy_k = op.gather_kept(dy_eff, sel)
+        if sdx and sdw:
+            dx, dw_compact = op.contract_gathered(dy_k, sel)
+        elif sdx:
+            dx = op.contract_gathered_dx(dy_k, sel)
+            dw_compact = None
+        else:
+            dx = op.dx_full(dy_eff)
+            dw_compact = op.contract_gathered_dw(dy_k, sel)
+        dw = op.scatter_dw(dw_compact, sel) if sdw else op.dw_full(dy_eff)
     return dx, dw

@@ -8,18 +8,14 @@ pipeline; this module supplies only the conv linear algebra:
 * **full / mask-mode contraction** — the VJP of the conv itself,
 * **gathered contraction** — the VJP of the conv restricted to the kept
   output channels, which XLA lowers to transposed convs with
-  ``C_out' = K`` (exactly the (1-D) FLOPs saving of Eq. 9),
-* **fused Pallas backward** — the default Pallas route
-  (``fuse_im2col=True``): ``kernels/ops.py::conv_dx_fused`` /
-  ``conv_dw_fused_scatter`` extract im2col patches inside the kernels'
-  HBM→VMEM index maps, so the ``[M, C_in*Kh*Kw]`` patch buffer is never
-  materialized. Grouped convs ride the same kernels in block-diagonal
-  form whenever per-group channel counts are block-aligned.
-* **canonical (im2col) lowering** — ``kernels/im2col.py`` columnizes the
-  conv so block-granular selection routes through the same Pallas
-  ``dx_gathered`` / ``dw_gathered_scatter`` kernels as ``sparse_dense``
-  when ``use_pallas=True, granularity="block"``. With ``fuse_im2col``
-  on this is only the A/B baseline; it materializes ``X2`` in HBM.
+  ``C_out' = K`` (exactly the (1-D) FLOPs saving of Eq. 9).
+
+Every conv site that drops a block takes the gathered contraction,
+``use_pallas`` or not: the conv offers the engine no Pallas lowering.
+On a TPU v5e XLA's gathered conv beat the Pallas conv kernels
+(``kernels/ops.py::conv_dx_fused`` / ``conv_dw_fused_scatter`` and the
+im2col ``dx_gathered`` / ``dw_gathered_scatter``) at every ResNet-18
+site that drops a block, on CIFAR and ImageNet shapes alike.
 
 Grouped convs select a balanced top-k per group (the engine's shard
 mechanism): a gathered grouped conv stays well-formed only when every
@@ -119,11 +115,11 @@ class _ConvOp(backward.ChannelSparseOp):
         return self._one_sided_vjp(dy_eff, wrt_x=False)
 
     def contract_gathered_dx(self, dy_k, sel):
-        w_k = jnp.take(self.w, sel.idx, axis=0)
+        w_k = self.take_kept(self.w, sel, 0)
         return self._one_sided_vjp(dy_k, wrt_x=True, w=w_k)
 
     def contract_gathered_dw(self, dy_k, sel):
-        w_k = jnp.take(self.w, sel.idx, axis=0)
+        w_k = self.take_kept(self.w, sel, 0)
         return self._one_sided_vjp(dy_k, wrt_x=False, w=w_k)
 
     def contract_gathered(self, dy_k, sel):
@@ -131,89 +127,8 @@ class _ConvOp(backward.ChannelSparseOp):
         # transposed convs XLA emits have C_out' = K, i.e. shrunk FLOPs.
         # Balanced per-group selection keeps kept channel j in group
         # j // k_loc, so feature_group_count survives the restriction.
-        w_k = jnp.take(self.w, sel.idx, axis=0)
+        w_k = self.take_kept(self.w, sel, 0)
         return self._vjp(w_k, dy_k)
-
-    def _explicit_padding(self) -> tuple[tuple[int, int], tuple[int, int]]:
-        """Resolve string padding to explicit per-dim (lo, hi) pairs.
-
-        The fused kernels address the zero-padded image directly, so they
-        need numbers; ``padtype_to_pads`` wants the *effective* (dilated)
-        filter extent."""
-        if isinstance(self.padding, str):
-            kh, kw = self.w.shape[2:]
-            eff = tuple((k - 1) * d + 1 for k, d in zip((kh, kw), self.dilation, strict=True))
-            pads = jax.lax.padtype_to_pads(
-                self.x.shape[2:], eff, self.stride, self.padding
-            )
-            return tuple(tuple(p) for p in pads)
-        return tuple(tuple(p) for p in self.padding)
-
-    def fused_backward(self, dy_eff, sel, sdx, sdw):
-        if not self.policy.fuse_im2col:
-            return None
-        if self.w.shape[2] == self.w.shape[3] == 1:
-            # 1x1: im2col is a reshape/slice, there is no patch buffer
-            # to fuse away — the canonical kernels are the cheaper path.
-            return None
-        bs = self.policy.block_size
-        c_out = self.c_out
-        if self.groups > 1 and c_out % (self.groups * bs) != 0:
-            # block-diagonal routing needs whole blocks per group
-            return None
-        # The traffic model is the routing authority: fuse only when the
-        # kernels' per-(tap × kept-block) re-fetches move fewer bytes
-        # than the [M, N] patch buffers they eliminate. All inputs are
-        # static, so this folds away under jit.
-        from repro.core import flops as F
-
-        bt, _, h_out, w_out = dy_eff.shape
-        model = functools.partial(
-            F.conv_backward_bytes_policy,
-            bt, h_out, w_out, self.x.shape[1], c_out, self.w.shape[2],
-            self.policy, groups=self.groups,
-        )
-        if model(fused=True) >= model(fused=False):
-            return None
-        from repro.kernels import ops as kops
-
-        pads = self._explicit_padding()
-        x, w = self._cast(self.x), self._cast(self.w)
-        dy_eff = dy_eff.astype(jnp.result_type(x.dtype, w.dtype))
-        nb = -(-c_out // bs)
-        # dense side of a mixed sparsify_dx/dw policy: every block kept
-        dense_idx = jnp.arange(nb, dtype=sel.block_idx.dtype)
-        kh, kw = self.w.shape[2:]
-        common = dict(
-            stride=self.stride, padding=pads, dilation=self.dilation,
-            groups=self.groups, block_size=bs,
-        )
-        dx = kops.conv_dx_fused(
-            dy_eff, w, sel.block_idx if sdx else dense_idx,
-            hw=self.x.shape[2:], **common,
-        )
-        dw2 = kops.conv_dw_fused_scatter(
-            x, dy_eff, sel.block_idx if sdw else dense_idx, kh=kh, kw=kw, **common,
-        )  # [Cg*Kh*Kw, C_out] with (c, kh, kw) row order -> OIHW
-        dw = dw2.T.reshape(c_out, self.w.shape[1], kh, kw)
-        return dx.astype(self._acc), dw.astype(self._acc)
-
-    def canonical(self, dy_eff):
-        if self.groups != 1:
-            return None
-        from repro.kernels import im2col
-
-        c_out, _, kh, kw = self.w.shape
-        x2, col2im, _ = im2col.conv_patches(
-            self._cast(self.x), kh, kw, self.stride, self.padding, self.dilation
-        )
-        return backward.CanonicalForm(
-            x2=x2,
-            w2=self._cast(im2col.flatten_filters(self.w)),
-            dy2=im2col.flatten_grad(dy_eff),
-            dx_from=col2im,
-            dw_from=lambda dw2: im2col.unflatten_filter_grad(dw2, self.w.shape),
-        )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))

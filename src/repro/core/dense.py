@@ -40,6 +40,7 @@ class _DenseOp(backward.ChannelSparseOp):
 
     channel_axis = 1
     dw_channel_axis = 1
+    block_slabs = False
 
     def __init__(self, x2: jax.Array, w: jax.Array, policy: SsPropPolicy):
         super().__init__(policy)

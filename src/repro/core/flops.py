@@ -8,7 +8,8 @@ The ``*_ssprop`` functions take the paper's nominal drop rate; the
 ``*_policy`` functions take an :class:`~repro.core.policy.SsPropPolicy`
 and count what the backward engine *actually* executes: block
 granularity rounds the keep count to whole ``block_size`` blocks, and
-the Pallas gathered kernels pay for their 128-aligned tile padding.
+the Pallas gathered kernels (dense sites only: conv sites contract
+through XLA's gathered conv) pay for their 128-aligned tile padding.
 The ``*_site`` functions are the per-site entry points: they accept a
 resolved :class:`~repro.core.policy.SitePolicies` table plus the call
 site's name, so a per-site policy program's total FLOPs are summed over
@@ -16,9 +17,10 @@ the resolved site table — each layer at its *own* keep count — rather
 than one global rate.
 
 Alongside FLOPs, :func:`conv_backward_bytes_policy` models the HBM
-*traffic* of one conv backward — materializing-im2col vs the fused
-Pallas kernels — and is both the roofline bytes-moved column and the
-gate the engine uses to decide when fusing actually wins.
+*traffic* of a conv backward in im2col form, the roofline bytes-moved
+column; :func:`conv_backward_bytes_breakdown` also counts the fused
+Pallas conv kernels' traffic, for those kernels' own checks. No engine
+route runs those kernels.
 
 These formulas drive the benchmark tables (paper Tables 4-7), the conv
 roofline rows, and the property test on the drop-rate lower bound
@@ -171,11 +173,9 @@ def conv_backward_flops_policy(
     """Eq. 9 with the engine's real keep counts instead of the nominal D.
 
     ``(4MN + M) * kept + M*C_out`` with ``M = Bt*H_out*W_out``,
-    ``N = C_in*K^2`` and ``kept = kept_channels(C_out, policy)``. On the
-    Pallas block path the two gathered matmuls run over 128-aligned
-    padded tiles (M, N padded to 128; kept padded to whole blocks), so
-    the 4MN term is counted at padded sizes — the honest cost of the
-    TPU lowering, visible whenever shapes are misaligned.
+    ``N = C_in*K^2`` and ``kept = kept_channels(C_out, policy)``. Every
+    route contracts unpadded: ``use_pallas`` sends no conv to the
+    kernels' 128-aligned tiles.
     """
     m = bt * h_out * w_out
     n = c_in * k * k
@@ -185,16 +185,8 @@ def conv_backward_flops_policy(
     kept = kept_channels(c_out, policy)
     # Eq. 6 decomposes per output element as 2N (dX) + 2N (dW) + 1 (db);
     # each side shrinks only when its sparsify_* flag is on.
-    if policy.use_pallas and policy.granularity == "block":
-        m_pad = _roundup(m, 128)
-        n_pad = _roundup(n, 128)
-        kept_pad = policy.keep_count(c_out) * policy.block_size
-        gathered = 2 * m_pad * n_pad * kept_pad
-        dx_term = gathered if sdx else 2 * m * n * c_out
-        dw_term = gathered if sdw else 2 * m * n * c_out
-    else:
-        dx_term = 2 * m * n * (kept if sdx else c_out)
-        dw_term = 2 * m * n * (kept if sdw else c_out)
+    dx_term = 2 * m * n * (kept if sdx else c_out)
+    dw_term = 2 * m * n * (kept if sdw else c_out)
     db_term = m * (kept if sdw else c_out)
     return int(dx_term + dw_term + db_term + m * c_out)
 
@@ -223,40 +215,6 @@ def dense_backward_flops_policy(
     return int(f + m * d_out)
 
 
-def _conv_fused_route(
-    bt: int, h_out: int, w_out: int, c_in: int, c_out: int, k: int,
-    policy: "SsPropPolicy", groups: int,
-) -> bool:
-    """Would the engine take the fused-im2col Pallas route for this conv?
-
-    Replicates :meth:`repro.core.conv._ConvOp.fused_backward`'s gate:
-    structural conditions (``fuse_im2col``, a real patch buffer to fuse
-    away, whole blocks per group, a selection that drops something, else
-    the engine contracts densely) plus the traffic-model min. The
-    auditor needs the routing decision statically to predict which
-    kernels appear in the jaxpr.
-    """
-    if not (
-        policy.active
-        and policy.use_pallas
-        and policy.granularity == "block"
-        and policy.fuse_im2col
-        and k > 1
-    ):
-        return False
-    if groups > 1 and c_out % (groups * policy.block_size) != 0:
-        return False
-    if _keeps_all(c_out, policy, groups):
-        return False
-    fus = conv_backward_bytes_policy(
-        bt, h_out, w_out, c_in, c_out, k, policy, fused=True, groups=groups
-    )
-    mat = conv_backward_bytes_policy(
-        bt, h_out, w_out, c_in, c_out, k, policy, fused=False, groups=groups
-    )
-    return fus < mat
-
-
 def conv_backward_contraction_bounds(
     bt: int,
     h_out: int,
@@ -267,30 +225,24 @@ def conv_backward_contraction_bounds(
     policy: "SsPropPolicy",
     *,
     groups: int = 1,
-    h_pad: int = None,
 ) -> tuple:
     """Exact ``(lo, hi)`` *contraction* FLOPs of one conv backward.
 
     The jaxpr-auditable core of :func:`conv_backward_flops_policy`: only
-    ``conv_general_dilated`` / ``dot_general`` / Pallas-kernel work — no
-    bias reduction, no importance pass (those are elementwise and the
-    walker doesn't count them). Groups-aware (``N_g = (C_in/G)*K²``),
-    unlike the legacy per-site tables which predate grouped convs.
-
-    ``lo == hi`` everywhere except the fused-im2col dX kernel, whose
-    grid sweeps every *padded-image* row and masks invalid taps with
-    ``pl.when`` — in the jaxpr that is a ``cond``, so the walker reports
-    an interval: ``lo`` counts only valid grid steps (``B*H_out`` rows),
-    ``hi`` the full grid (``B*H_pad`` rows). ``h_pad`` defaults to the
-    stride-1 'SAME'-ish ``H_out + K - 1`` (the bytes model's
-    convention); pass the true padded height for exact bounds.
+    ``conv_general_dilated`` / ``dot_general`` work — no bias reduction,
+    no importance pass (those are elementwise and the walker doesn't
+    count them). Groups-aware (``N_g = (C_in/G)*K²``), unlike the legacy
+    per-site tables which predate grouped convs. Every conv route is
+    unconditional, so ``lo == hi``; the interval form matches the
+    walker's.
 
     A site whose selection keeps every channel (:func:`_keeps_all`)
-    contracts densely, as at drop rate 0: ``2 * full_side``.
-
-    On every other non-fused route, ``conv_backward_flops_policy == lo
-    + db_term + M*C_out`` for ``groups == 1``; on the dense one the
-    importance term ``M*C_out`` is not paid.
+    contracts densely, as at drop rate 0: ``2 * full_side``. Every other
+    site takes XLA's gathered conv at the kept width, ``use_pallas`` or
+    not (the conv offers the engine no Pallas lowering), and
+    ``conv_backward_flops_policy == lo + db_term + M*C_out`` for
+    ``groups == 1``; on the dense route the importance term ``M*C_out``
+    is not paid.
     """
     m = bt * h_out * w_out
     cg = c_in // groups
@@ -304,44 +256,6 @@ def conv_backward_contraction_bounds(
 
     width = gather_width(c_out, policy, n_shards)
     gathered_side = 2 * m * n_g * width
-
-    if (
-        policy.use_pallas
-        and policy.granularity == "block"
-    ):
-        bs = policy.block_size
-        nb = -(-c_out // bs)
-        if _conv_fused_route(bt, h_out, w_out, c_in, c_out, k, policy, groups):
-            # Every dX dot sits under the kernel's pl.when(valid) — a
-            # cond in the jaxpr — so the unconditional floor is the dW
-            # kernel alone (lo), and the ceiling bills the dX grid's
-            # full padded-row sweep (hi). The true cost, valid steps
-            # only, is 2*M*N_g*kept_dx + dw_term, inside the interval.
-            if h_pad is None:
-                h_pad = h_out + k - 1
-            kept_dx = width if sdx else nb * bs
-            kept_dw = width if sdw else nb * bs
-            dw_term = 2 * m * n_g * kept_dw
-            dx_hi = 2 * (bt * h_pad * w_out) * n_g * kept_dx
-            return (int(dw_term), int(dx_hi + dw_term))
-        if groups == 1:
-            # canonical-form gathered kernels over 128-padded tiles;
-            # a non-sparsified side is a plain unpadded jnp.matmul.
-            # conv_general_dilated_patches (X2) and its VJP (col2im)
-            # are themselves convs with K² identity output channels —
-            # 2*M*N*K² FLOPs each, the honest price of materializing.
-            n = c_in * k * k
-            m_pad = _roundup(m, 128)
-            n_pad = _roundup(n, 128)
-            gathered_pad = 2 * m_pad * n_pad * width
-            dx_term = gathered_pad if sdx else full_side
-            dw_term = gathered_pad if sdw else full_side
-            im2col_term = 2 * (2 * m * n * k * k)
-            t = int(dx_term + dw_term + im2col_term)
-            return (t, t)
-        # groups > 1 without the fused route: the canonical lowering
-        # declines grouped convs, so the engine falls back to the
-        # gathered-VJP path below.
 
     dx_term = gathered_side if sdx else full_side
     dw_term = gathered_side if sdw else full_side
@@ -416,42 +330,24 @@ def conv_backward_bytes_policy(
     c_out: int,
     k: int,
     policy: "SsPropPolicy",
-    fused: bool = None,
     itemsize: int = 4,
     groups: int = 1,
 ) -> int:
-    """HBM bytes one conv backward moves under ``policy``.
+    """HBM bytes one conv backward moves in im2col form.
 
     The FLOPs model (Eq. 6/9) says what the backward *computes*; this
-    says what it *transfers* — the roofline memory term and the quantity
-    the Pallas im2col fusion actually attacks. Two regimes:
-
-    * **Materializing** (``fused=False``): the canonical im2col path
-      builds real patch buffers — ``X2 [M, N]`` written then read by the
-      dW kernel, ``dX2 [M, N]`` written by the dX kernel then read back
-      by col2im (``M = Bt*H_out*W_out``, ``N = C_in*K²``). Those four
-      ``M*N`` transfers dominate and do **not** shrink with sparsity.
-    * **Fused** (``fused=True``): mirrors the fused kernel grids in
-      :mod:`repro.kernels.gathered_matmul` — padded-image rows and
-      cotangent panels are re-fetched once per (tap × kept-block) grid
-      step, the compact filter is fetched into VMEM once, and no
-      ``[M, N]`` buffer exists anywhere. Traffic scales with the kept
-      block count, so sparsity cuts bytes as well as FLOPs.
-
-    ``fused=None`` routes exactly like the engine (:func:`_conv_fused_route`):
-    the fused model when the policy's Pallas/fuse_im2col path applies to
-    this conv and it moves fewer bytes (the gate
-    :meth:`repro.core.conv._ConvOp.fused_backward` applies), the
-    materializing model otherwise, as at drop rate 0 for a site that
-    keeps every channel and contracts densely.
+    says what it *transfers* — the roofline memory term. It counts the
+    im2col form's patch buffers — ``X2 [M, N]`` written then read by the
+    dW product, ``dX2 [M, N]`` written by the dX product then read back
+    by col2im (``M = Bt*H_out*W_out``, ``N = C_in*K²``). Those four
+    ``M*N`` transfers dominate and do **not** shrink with sparsity. XLA's
+    gathered conv, which the engine runs (:mod:`repro.core.conv`), builds
+    no such buffer, so this bounds its traffic from above.
     Geometry is counted at stride 1 / 'SAME'-ish padding
-    (``H_pad = H_out + K - 1``) — walkers don't carry strides, and both
-    regimes use the same approximation.
+    (``H_pad = H_out + K - 1``) — walkers don't carry strides.
     """
-    if fused is None:
-        fused = _conv_fused_route(bt, h_out, w_out, c_in, c_out, k, policy, groups)
     parts = conv_backward_bytes_breakdown(
-        bt, h_out, w_out, c_in, c_out, k, policy, fused=fused, groups=groups
+        bt, h_out, w_out, c_in, c_out, k, policy, fused=False, groups=groups
     )
     return sum(parts.values()) * itemsize
 
@@ -470,12 +366,16 @@ def conv_backward_bytes_breakdown(
 ) -> dict[str, int]:
     """Per-component *element* counts behind the bytes model.
 
-    :func:`conv_backward_bytes_policy` is exactly
-    ``sum(breakdown.values()) * itemsize``; this exposes the terms so the
-    static checker can cross-validate the fused kernel components
-    against a grid-walk traffic emulation of the kernel specs
-    (:mod:`repro.analysis.pallas_check`). Fused kernel-side keys map 1:1
-    onto per-operand fetch totals of the ``conv_dw_fused`` /
+    With ``fused=False``, :func:`conv_backward_bytes_policy` is exactly
+    ``sum(breakdown.values()) * itemsize``. With ``fused=True`` the
+    terms mirror the fused Pallas conv kernel grids in
+    :mod:`repro.kernels.gathered_matmul` — padded-image rows and
+    cotangent panels re-fetched once per (tap × kept-block) grid step,
+    the compact filter fetched into VMEM once, no ``[M, N]`` buffer —
+    so the static checker can cross-validate them against a grid-walk
+    traffic emulation of the kernel specs
+    (:mod:`repro.analysis.pallas_check`). Those keys map 1:1 onto
+    per-operand fetch totals of the ``conv_dw_fused`` /
     ``conv_dx_fused`` grids under sequential-grid revisit elision:
 
     * ``dw.xg_rows`` / ``dw.dy_panels`` / ``dw.out_flush`` — the dW
@@ -542,7 +442,6 @@ def conv_backward_bytes_site(
     k: int,
     policy: "PolicyLike",
     site: str = "",
-    fused: bool = None,
     itemsize: int = 4,
 ) -> int:
     """:func:`conv_backward_bytes_policy` for one named call site."""
@@ -550,7 +449,7 @@ def conv_backward_bytes_site(
 
     return conv_backward_bytes_policy(
         bt, h_out, w_out, c_in, c_out, k, policy_for(policy, site),
-        fused=fused, itemsize=itemsize,
+        itemsize=itemsize,
     )
 
 

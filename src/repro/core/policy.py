@@ -75,15 +75,11 @@ class SsPropPolicy:
         shrink to the kept channels (gather mode, FLOPs actually drop).
       sparsify_dx / sparsify_dw: apply sparsity to the input-gradient /
         weight-gradient matmul. Paper uses both.
-      use_pallas: route the shrunk backward matmuls through the Pallas
-        gathered-matmul kernels (TPU target; interpret-mode on CPU)
-        rather than plain jnp gather+dot.
-      fuse_im2col: with ``use_pallas`` on a conv site, extract im2col
-        patches inside the kernels' HBM→VMEM index maps (the fused
-        ``conv_dx_fused`` / ``conv_dw_fused`` kernels) instead of
-        materializing the ``[M, C_in*Kh*Kw]`` patch buffer in HBM
-        first. Default on; turn off to A/B against the materializing
-        canonical-form path (``kernels/im2col.py``).
+      use_pallas: route a dense site's shrunk backward matmuls through
+        the Pallas gathered-matmul kernels (TPU target; interpret-mode
+        on CPU) rather than plain jnp gather+dot. Conv sites ignore it:
+        they take XLA's gathered conv, which beat the Pallas conv
+        kernels on a TPU v5e at every ResNet-18 site that drops a block.
       seed: RNG seed for ``selection="random"``.
     """
 
@@ -98,7 +94,6 @@ class SsPropPolicy:
     sparsify_dx: bool = True
     sparsify_dw: bool = True
     use_pallas: bool = False
-    fuse_im2col: bool = True  # conv sites: patch extraction in-kernel
     tp_shards: int = 0  # >0: TP-local per-shard top-k (comm-free gather;
     #   equal k per shard -> load-balanced shrunk matmuls). §Perf iter 1.
     bwd_dtype: str = ""  # "bfloat16": backward matmuls/psums in bf16

@@ -36,7 +36,6 @@ from repro.analysis.lints import lint_step_counts
 from repro.analysis.report import INFO, Report
 from repro.configs.base import ShapeConfig
 from repro.configs.registry import ARCH_IDS, get_config
-from repro.core import flops as ftab
 from repro.core.policy import PolicyProgram, PolicyRules, SsPropPolicy
 from repro.core.schedulers import make_schedule
 
@@ -121,22 +120,16 @@ def analyze_conv(args) -> list[Report]:
     sites = [g[0] for g in geoms]
     table = program.resolve(sites).peak()
 
+    # conv sites take XLA's gathered conv, never a Pallas kernel, so a
+    # conv model has no kernel launch geometry to check
     sav = Report(f"savings:{args.model}")
-    pal = Report(f"pallas:{args.model}")
     for site, c_in, c_out, k, h_out, w_out in geoms:
-        pol = table[site]
         savings.audit_conv_site(
-            sav, site, args.batch, h_out, w_out, c_in, c_out, k, pol
+            sav, site, args.batch, h_out, w_out, c_in, c_out, k, table[site]
         )
-        if ftab._conv_fused_route(
-            args.batch, h_out, w_out, c_in, c_out, k, pol, 1
-        ):
-            pallas_check.check_conv_fused_site(
-                pal, site, args.batch, h_out, w_out, c_in, c_out, k, pol
-            )
     ret = Report(f"retrace:{args.model}")
     retrace.check_train_retrace(ret, program, sites)
-    return [sav, pal, ret]
+    return [sav, ret]
 
 
 def analyze_lm(args) -> list[Report]:

@@ -2,7 +2,8 @@
 tables (exact, over a policy × groups × dtype grid), the seeded
 regressions each lint must catch (planted f32 upcast, planted host
 callback, out-of-bounds index map), retrace budgets, the Pallas traffic
-cross-check, and the docs/CLI static checker."""
+cross-check of the conv kernels (which no engine route runs), and the
+docs/CLI static checker."""
 import dataclasses
 import os
 import sys
@@ -84,7 +85,7 @@ class TestConvAuditGrid:
         dense = ftab.conv_backward_contraction_bounds(2, 8, 8, 64, c_out, k, DENSE)
         assert (counts.flops_lo, counts.flops_hi) == dense
         assert ftab.gather_width(c_out, pol) == ftab.kept_channels(c_out, pol) == c_out
-        assert not ftab._conv_fused_route(2, 8, 8, 64, c_out, k, pol, 1)
+        assert {c.prim for c in counts.contractions} == {"conv_general_dilated"}
 
     def test_strided_site_audits_via_stride1_twin(self):
         # the probe is stride-1 by construction; the tables carry no
@@ -94,7 +95,7 @@ class TestConvAuditGrid:
             rep, "site", 2, 4, 4, 16, 32, 3, tpu_default(0.8)
         )
         lo, hi = ftab.conv_backward_contraction_bounds(
-            2, 4, 4, 16, 32, 3, tpu_default(0.8), h_pad=4 + 3 - 1
+            2, 4, 4, 16, 32, 3, tpu_default(0.8)
         )
         assert (counts.flops_lo, counts.flops_hi) == (lo, hi)
         assert not rep.errors()
@@ -237,27 +238,35 @@ class TestSeededRegressions:
 
 class TestPallasTraffic:
     def test_fused_conv_traffic_matches_bytes_model(self):
+        # the fused conv kernels, checked on their own: no engine route
+        # runs them (the site audits as XLA's gathered conv alone)
         pol = dataclasses.replace(
             tpu_default(0.8), use_pallas=True, block_size=32
         )
-        assert ftab._conv_fused_route(2, 8, 8, 32, 64, 3, pol, 1)
+        counts = savings.audit_conv_site(Report("t"), "site", 2, 8, 8, 32, 64, 3, pol)
+        assert {c.prim for c in counts.contractions} == {"conv_general_dilated"}
         rep = Report("t")
         pallas_check.check_conv_fused_site(rep, "site", 2, 8, 8, 32, 64, 3, pol)
         assert not rep.errors(), [f.message for f in rep.errors()]
 
     def test_resnet18_fused_route_leaves_sites_that_keep_every_channel(self):
-        # at 0.8 in 128-channel blocks only the 256- and 512-channel 3x3
-        # sites run the fused kernels, so only they are audited for traffic
+        # at 0.8 in 128-channel blocks no ResNet-18 site runs a Pallas
+        # kernel: the ten that drop a block audit as XLA's gathered conv
+        # at their kept width, the ten that keep every channel as dense
         from repro.models import resnet
 
         pol = dataclasses.replace(tpu_default(0.8), use_pallas=True)
-        fused = {
-            site: c_out
-            for site, c_in, c_out, k, h, w in resnet.iter_conv_shapes("resnet18", (3, 32, 32))
-            if ftab._conv_fused_route(128, h, w, c_in, c_out, k, pol, 1)
-        }
-        assert sorted(set(fused.values())) == [256, 512]
-        assert len(fused) == 8
+        gathered = []
+        for site, c_in, c_out, k, h, w in resnet.iter_conv_shapes("resnet18", (3, 32, 32)):
+            rep = Report("t")
+            counts = savings.audit_conv_site(rep, site, 2, h, w, c_in, c_out, k, pol)
+            assert not rep.errors(), [f.message for f in rep.errors()]
+            assert {c.prim for c in counts.contractions} == {"conv_general_dilated"}
+            dense = ftab.conv_backward_contraction_bounds(2, h, w, c_in, c_out, k, DENSE)
+            if (counts.flops_lo, counts.flops_hi) != dense:
+                gathered.append(c_out)
+        assert sorted(set(gathered)) == [256, 512]
+        assert len(gathered) == 10
 
     def test_paged_attention_geometry(self):
         rep = Report("t")

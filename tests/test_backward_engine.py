@@ -140,8 +140,9 @@ class TestDenseParityGrid:
 
 
 class TestPallasParity:
-    """The acceptance-criterion paths: block granularity through the
-    Pallas gathered kernels, interpret mode on CPU, fp32 tolerance."""
+    """Block granularity with ``use_pallas``: a conv site takes XLA's
+    gathered conv (no Pallas kernel), fp32 tolerance against the mask
+    oracle."""
 
     @pytest.mark.parametrize(
         "stride,padding,dilation", [(1, 1, 1), (2, 0, 1), (1, 1, 2)]
@@ -180,41 +181,55 @@ class TestPallasParity:
             monkeypatch.setattr(kops, name, spy)
         return calls
 
+    KERNELS = ("conv_dx_fused", "conv_dw_fused_scatter", "dx_gathered", "dw_gathered_scatter")
+
+    @staticmethod
+    def _spy_gathered(monkeypatch):
+        """Count calls of the conv's gathered contraction (XLA's conv VJP
+        at the kept output channels)."""
+        from repro.core import conv
+
+        calls = []
+        real = conv._ConvOp.contract_gathered
+
+        def spy(self, dy_k, sel):
+            calls.append(dy_k.shape)
+            return real(self, dy_k, sel)
+
+        monkeypatch.setattr(conv._ConvOp, "contract_gathered", spy)
+        return calls
+
     def test_conv_pallas_path_actually_routes_through_kernels(self, monkeypatch):
-        # fuse_im2col is on by default: the fused kernels take the call,
-        # the materializing canonical kernels are never touched.
-        calls = self._spy(
-            monkeypatch,
-            ("conv_dx_fused", "conv_dw_fused_scatter",
-             "dx_gathered", "dw_gathered_scatter"),
-        )
+        # use_pallas no longer reaches a conv kernel: the call takes XLA's
+        # gathered conv at the kept channels (8 of 16), and no kernel runs
+        calls = self._spy(monkeypatch, self.KERNELS)
+        gathered = self._spy_gathered(monkeypatch)
         _conv_grads(_pol("block", "", block_size=8, use_pallas=True), 1, 1, 1, 1)
-        assert calls["conv_dx_fused"] == 1 and calls["conv_dw_fused_scatter"] == 1
-        assert calls["dx_gathered"] == 0 and calls["dw_gathered_scatter"] == 0
+        assert calls == dict.fromkeys(self.KERNELS, 0)
+        assert gathered == [(2, 8, 8, 8)]
 
     def test_conv_pallas_fuse_off_routes_materializing(self, monkeypatch):
-        calls = self._spy(
-            monkeypatch,
-            ("conv_dx_fused", "conv_dw_fused_scatter",
-             "dx_gathered", "dw_gathered_scatter"),
-        )
-        _conv_grads(
-            _pol("block", "", block_size=8, use_pallas=True, fuse_im2col=False),
-            1, 1, 1, 1,
-        )
-        assert calls["dx_gathered"] == 1 and calls["dw_gathered_scatter"] == 1
-        assert calls["conv_dx_fused"] == 0 and calls["conv_dw_fused_scatter"] == 0
+        # neither the fused nor the materializing kernels: use_pallas on
+        # and off take the same gathered XLA conv, bit for bit
+        calls = self._spy(monkeypatch, self.KERNELS)
+        gathered = self._spy_gathered(monkeypatch)
+        pol = _pol("block", "", block_size=8, use_pallas=True)
+        g_on = _conv_grads(pol, 1, 1, 1, 1)
+        g_off = _conv_grads(dataclasses.replace(pol, use_pallas=False), 1, 1, 1, 1)
+        assert calls == dict.fromkeys(self.KERNELS, 0)
+        assert gathered == [(2, 8, 8, 8)] * 2
+        for a, b in zip(g_on, g_off, strict=True):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     @pytest.mark.parametrize("stride,padding,dilation,groups", GEOMS)
     def test_conv_fused_equals_materialized(
         self, stride, padding, dilation, groups
     ):
-        # the tentpole contract: the fused index-map kernels compute the
-        # same backward as the materializing canonical path, across the
-        # full geometry grid (selection is identical — only the lowering
-        # differs).
+        # the gathered route that use_pallas now takes equals the
+        # mask-mode oracle across the full geometry grid (one selection,
+        # only the contraction differs)
         pol = _pol("block", "", block_size=4, use_pallas=True)
-        ref = dataclasses.replace(pol, fuse_im2col=False)
+        ref = _pol("block", "", block_size=4, mask=True)
         g1 = _conv_grads(pol, stride, padding, dilation, groups)
         g2 = _conv_grads(ref, stride, padding, dilation, groups)
         for name, a, r in zip(("dx", "dw", "db"), g1, g2, strict=True):
@@ -223,15 +238,17 @@ class TestPallasParity:
             )
 
     def test_conv_pallas_grouped_routes_fused_block_diagonal(self, monkeypatch):
-        # grouped convs route onto the SAME fused kernels via the
-        # block-diagonal canonical form (whole blocks per group) — the
-        # old framework-VJP fallback is only for indivisible shapes.
-        calls = self._spy(monkeypatch, ("conv_dx_fused", "conv_dw_fused_scatter"))
+        # a grouped conv whose groups hold whole blocks takes the balanced
+        # per-group gathered conv (each group keeps 4 of its 8), as every
+        # grouped conv does; no block-diagonal kernel runs
+        calls = self._spy(monkeypatch, self.KERNELS)
+        gathered = self._spy_gathered(monkeypatch)
         pol = _pol("block", "", block_size=4, use_pallas=True)
         ref = _pol("block", "", block_size=4, mask=True)
         g1 = _conv_grads(pol, 1, 1, 1, 2)
         g2 = _conv_grads(ref, 1, 1, 1, 2)
-        assert calls["conv_dx_fused"] == 1 and calls["conv_dw_fused_scatter"] == 1
+        assert calls == dict.fromkeys(self.KERNELS, 0)
+        assert gathered == [(2, 8, 8, 8)]
         for a, r in zip(g1, g2, strict=True):
             np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-3, atol=1e-4)
 
@@ -250,6 +267,53 @@ class TestPallasParity:
             np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=2e-4, atol=1e-5)
 
 
+class TestConvBlockGather:
+    """Where selection keeps whole blocks, the engine gathers and scatters
+    a conv's block slabs; they hold exactly what the channel take and
+    scatter would (ragged tails keep the channel form)."""
+
+    @pytest.mark.parametrize(
+        "c_out,groups,block_size",
+        [(16, 1, 8), (32, 1, 8), (32, 2, 8), (64, 2, 16), (20, 1, 8)],
+    )
+    def test_block_slabs_equal_channel_take(self, c_out, groups, block_size):
+        from repro.core import backward, conv
+
+        pol = _pol("block", "", block_size=block_size, use_pallas=True)
+        ks = jax.random.split(jax.random.PRNGKey(11), 4)
+        x = jax.random.normal(ks[0], (2, 4, 6, 6))
+        w = jax.random.normal(ks[1], (c_out, 4 // groups, 3, 3))
+        dy = jax.random.normal(ks[2], (2, c_out, 6, 6))
+        op = conv._ConvOp(x, w, (1, 1), ((1, 1), (1, 1)), (1, 1), groups, pol)
+        sel = sparsity.select(dy, pol, channel_axis=1, n_shards=op.selection_shards(pol))
+        assert op._whole_blocks(sel) == (c_out % block_size == 0)
+        dw_k = jax.random.normal(ks[3], (sel.k, *w.shape[1:]))
+        dy_k = jnp.take(dy, sel.idx, axis=1)
+        if sel.valid is not None:
+            dy_k = dy_k * sel.valid.reshape(1, -1, 1, 1)
+        for a, r in (
+            (op.gather_kept(dy, sel), dy_k),
+            (op.scatter_dw(dw_k, sel), backward.scatter_channels(dw_k, sel.idx, c_out, 0)),
+            (op.take_kept(w, sel, 0), jnp.take(w, sel.idx, axis=0)),
+        ):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
+
+    def test_dense_sites_keep_the_channel_form(self):
+        from repro.core import dense
+
+        pol = _pol("block", "", block_size=8)
+        ks = jax.random.split(jax.random.PRNGKey(12), 3)
+        x2 = jax.random.normal(ks[0], (6, 4))
+        w = jax.random.normal(ks[1], (4, 32))
+        dy = jax.random.normal(ks[2], (6, 32))
+        op = dense._DenseOp(x2, w, pol)
+        sel = sparsity.select(dy, pol, channel_axis=1, n_shards=op.selection_shards(pol))
+        assert sel.block_idx is not None and not op._whole_blocks(sel)
+        np.testing.assert_array_equal(
+            np.asarray(op.gather_kept(dy, sel)), np.asarray(jnp.take(dy, sel.idx, axis=1))
+        )
+
+
 class TestAllKeptSites:
     """A site whose selection keeps every channel (one 128-channel block at
     0.8) contracts densely: no selection, no Pallas kernel, and the same
@@ -258,7 +322,7 @@ class TestAllKeptSites:
 
     POLICY = dataclasses.replace(tpu_default(0.8), use_pallas=True)
     # (c_out, groups, tp_shards, block_size): whole blocks per group or
-    # shard where the selected route can reach the Pallas kernels
+    # shard where block-diagonal layouts could hold them
     SITES = [
         (64, 1, 0, 128),
         (128, 1, 0, 128),
@@ -274,8 +338,6 @@ class TestAllKeptSites:
             self.POLICY, tp_shards=tp_shards, block_size=block_size
         )
         ks = jax.random.split(jax.random.PRNGKey(7), 3)
-        # large enough that the fused kernels move fewer bytes than the
-        # patch buffers, so the selected route takes them
         x = jax.random.normal(ks[0], (2, 32, 16, 16))
         w = jax.random.normal(ks[1], (c_out, 32 // groups, 3, 3)) * 0.2
         dy = jax.random.normal(ks[2], (2, c_out, 16, 16))
@@ -300,7 +362,7 @@ class TestAllKeptSites:
         from repro.core import backward
 
         sel = sparsity.select(dy, pol, channel_axis=1, n_shards=op.selection_shards(pol))
-        return backward._contract_selected(pol, op, dy, sel, 1, True, True)
+        return backward._contract_selected(pol, op, dy, sel, True, True)
 
     @pytest.mark.parametrize("bias", [False, True])
     @pytest.mark.parametrize("c_out,groups,tp_shards,block_size", SITES)
@@ -348,19 +410,27 @@ class TestAllKeptSites:
     def test_selected_route_reaches_the_fused_kernels(
         self, monkeypatch, c_out, groups, tp_shards, block_size
     ):
-        # the comparison above is against the fused Pallas route wherever
-        # whole blocks allow it (64 channels in two groups of 32 cannot
-        # hold a 128-channel block, so that case selects through XLA)
+        # the comparison above is against XLA's gathered conv, the route a
+        # selecting conv site takes with use_pallas on: the conv's gathered
+        # contraction runs over every channel, and no Pallas kernel does
+        from repro.core import conv
         from repro.kernels import ops as kops
 
-        calls = []
-        real = kops.conv_dx_fused
+        kernel_calls, gathered = [], []
+        real_kernel, real_gathered = kops.conv_dx_fused, conv._ConvOp.contract_gathered
         monkeypatch.setattr(
-            kops, "conv_dx_fused", lambda *a, **kw: calls.append(1) or real(*a, **kw)
+            kops, "conv_dx_fused",
+            lambda *a, **kw: kernel_calls.append(1) or real_kernel(*a, **kw),
+        )
+        monkeypatch.setattr(
+            conv._ConvOp, "contract_gathered",
+            lambda self, dy_k, sel: gathered.append(dy_k.shape[1])
+            or real_gathered(self, dy_k, sel),
         )
         pol, op, dy, _ = self._conv(c_out, groups, tp_shards, block_size)
         self._selected_route(pol, op, dy)
-        assert calls
+        sel = sparsity.select(dy, pol, channel_axis=1, n_shards=op.selection_shards(pol))
+        assert gathered == [sel.k] and sel.k >= c_out and not kernel_calls
 
     @pytest.mark.parametrize(
         "c_out,mask,kept_all",
@@ -368,9 +438,9 @@ class TestAllKeptSites:
          (256, False, False), (256, True, False)],
     )
     def test_route_in_jaxpr(self, c_out, mask, kept_all):
-        # an engaged site holds no kernel and no top-k; a site that drops
-        # a block (256 channels = 2 blocks, 1 kept) still selects and, off
-        # mask mode, runs the fused kernel
+        # an engaged site holds no top-k; a site that drops a block (256
+        # channels = 2 blocks, 1 kept) still selects and, off mask mode,
+        # contracts through XLA's gathered conv: no site holds a kernel
         pol = dataclasses.replace(self.POLICY, mask_mode=mask)
         x = jnp.zeros((2, 64, 16, 16))
         w = jnp.zeros((c_out, 64, 3, 3))
@@ -380,10 +450,10 @@ class TestAllKeptSites:
 
         text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, w))
         assert ("top_k" in text) == (not kept_all)
-        if kept_all or mask:
-            assert "pallas_call" not in text
-        else:
-            assert "conv_dx_fused" in text and "pallas_call" in text
+        assert "pallas_call" not in text
+        if c_out == 256:
+            # the gathered route contracts over the kept block's filters
+            assert ("f32[128,64,3,3]" in text) == (not mask)
 
 
 class TestRaggedTailRegression:

@@ -3,8 +3,10 @@ carries the site's name scope in its HLO ``op_name``, forward and
 backward; the sparse backward's selection and contraction carry
 ``ssprop_select`` and ``ssprop_contract`` on every route, and a site whose
 selection would keep every channel contracts under
-``ssprop_contract/ssprop_all_kept`` and selects nothing; the TP-local
-route contracts under ``ssprop_contract/ssprop_tp``; attention's own work
+``ssprop_contract/ssprop_all_kept`` and selects nothing; the generic
+gathered route, which every conv site that drops a block takes, contracts
+under ``ssprop_contract/ssprop_gathered``; the TP-local route contracts
+under ``ssprop_contract/ssprop_tp``; attention's own work
 carries ``attn_core`` and the LM loss head ``lm_loss``; the optimizer's
 update carries ``adam``. Scopes are metadata: they change no instruction.
 """
@@ -29,16 +31,17 @@ _LOC = re.compile(r'loc\("([^"]*)"')
 _OPCODE = re.compile(r"^\s*(?:ROOT\s+)?%\S+ = .*? ([a-z][a-z0-9_-]*)\(", re.M)
 
 BLOCK = tpu_default(0.5)  # 256 channels: 1 of 2 128-channel blocks kept
+GATHERED = "ssprop_contract/ssprop_gathered/transpose(jvp())"
 ROUTES = {
     # route: (policy, op_name fragment only that route emits)
     "dense": (SsPropPolicy(), "ssprop_contract/transpose"),
     "mask": (dataclasses.replace(BLOCK, mask_mode=True), "ssprop_contract/mul"),
-    "gathered": (SsPropPolicy(drop_rate=0.5), "ssprop_contract/transpose(jvp())"),
-    "canonical": (
-        dataclasses.replace(BLOCK, use_pallas=True, fuse_im2col=False),
-        "ssprop_contract/jit(dx_gathered)",
-    ),
-    "fused": (dataclasses.replace(BLOCK, use_pallas=True), "ssprop_contract/jit(conv_dx_fused)"),
+    "gathered": (SsPropPolicy(drop_rate=0.5), GATHERED),
+    # the policies that reached the Pallas conv kernels (the canonical
+    # route also ran dX alone beside a dense dW): a conv now takes XLA's
+    # gathered conv under both
+    "canonical": (dataclasses.replace(BLOCK, use_pallas=True, sparsify_dw=False), GATHERED),
+    "fused": (dataclasses.replace(BLOCK, use_pallas=True), GATHERED),
     # one 256-channel block, so selection would keep every channel
     "all_kept": (
         dataclasses.replace(BLOCK, use_pallas=True, block_size=256),
@@ -53,7 +56,6 @@ def _hlo(fn, *args) -> str:
 
 def _conv_grad_hlo(policy, site=SITE) -> str:
     kx, kw = jax.random.split(jax.random.key(0))
-    # large enough that the fused kernels move fewer bytes than the patches
     x = jax.random.normal(kx, (2, 64, 16, 16), jnp.float32)
     p = layers.conv2d_init(kw, 256, 64, 3)
 
@@ -73,35 +75,68 @@ def test_conv_site_and_stages_name_every_route(route):
     assert any(f"{bwd}/{marker}" in n for n in names), route
     selected = any(f"{bwd}/ssprop_select/" in n for n in names)
     assert selected == (route not in ("dense", "all_kept"))
+    assert not any("pallas" in n or "_fused" in n or "dx_gathered" in n for n in names)
 
 
-def test_resnet18_scopes_the_sites_that_keep_every_channel():
-    """At 0.8 in 128-channel blocks, the ten ResNet-18 sites of 64 and 128
-    channels keep their only block: their backward runs under
-    ``ssprop_all_kept`` and selects nothing; the ten of 256 and 512
-    channels select and never enter that scope."""
+@pytest.fixture(scope="module")
+def resnet18_step():
+    """The ResNet-18 training step at drop rate 0.8 with ``use_pallas``
+    on, as the benchmark's sparse cells build it: its jaxpr and the
+    scope names of its lowering."""
     policy = dataclasses.replace(tpu_default(0.8), use_pallas=True)
     params = jax.eval_shape(
         lambda r: resnet.init_params("resnet18", r, 10), jax.random.key(0)
     )
     step = steps.make_classifier_step("resnet18", policy, adam.AdamConfig())
-    lowered = jax.jit(step).lower(
+    args = (
         params, jax.eval_shape(adam.init, params),
         jax.ShapeDtypeStruct((2, 3, 32, 32), jnp.float32),
         jax.ShapeDtypeStruct((2,), jnp.int32),
     )
-    names = set(_LOC.findall(lowered.as_text(debug_info=True)))
+    names = set(_LOC.findall(jax.jit(step).lower(*args).as_text(debug_info=True)))
+    return jax.make_jaxpr(step)(*args), names
+
+
+def _resnet18_sites():
     sites, _ = resnet.site_names("resnet18")
     widths = {s: c_out for s, _, c_out, *_ in resnet.iter_conv_shapes("resnet18", (3, 32, 32))}
+    return sites, widths
 
-    def scoped(site, stage):
-        return any(f"transpose(jvp({site}))/{stage}/" in n for n in names)
 
-    kept_all = [s for s in sites if scoped(s, "ssprop_contract/ssprop_all_kept")]
-    selecting = [s for s in sites if scoped(s, "ssprop_select")]
+def _scoped(names, site, stage):
+    return any(f"transpose(jvp({site}))/{stage}/" in n for n in names)
+
+
+def test_resnet18_scopes_the_sites_that_keep_every_channel(resnet18_step):
+    """At 0.8 in 128-channel blocks, the ten ResNet-18 sites of 64 and 128
+    channels keep their only block: their backward runs under
+    ``ssprop_all_kept`` and selects nothing; the ten of 256 and 512
+    channels select and never enter that scope."""
+    _, names = resnet18_step
+    sites, widths = _resnet18_sites()
+    kept_all = [s for s in sites if _scoped(names, s, "ssprop_contract/ssprop_all_kept")]
+    selecting = [s for s in sites if _scoped(names, s, "ssprop_select")]
     assert kept_all == [s for s in sites if widths[s] <= 128]
     assert len(kept_all) == 10
     assert selecting == [s for s in sites if s not in kept_all]
+
+
+def test_resnet18_step_holds_no_kernel_and_gathers_the_sites_that_drop_a_block(
+    resnet18_step,
+):
+    """With ``use_pallas`` on, the ResNet-18 step at 0.8 runs no Pallas
+    kernel: the ten sites of 256 and 512 channels, which drop a block,
+    contract through XLA's gathered conv under ``ssprop_gathered``, and
+    no other site does."""
+    jaxpr, names = resnet18_step
+    assert "pallas_call" not in str(jaxpr)
+    sites, widths = _resnet18_sites()
+    gathered = [s for s in sites if _scoped(names, s, "ssprop_contract/ssprop_gathered")]
+    assert gathered == [s for s in sites if widths[s] > 128]
+    assert gathered == [
+        f"block_{b}/{c}" for b in (4, 5, 6, 7) for c in ("conv1", "conv2", "down")
+        if c != "down" or b in (4, 6)
+    ]
 
 
 def test_dense_site_is_scoped():
